@@ -62,6 +62,7 @@ from repro.core.samplers import (SamplerSpec, Sampler, build_plan,
                                  get_family, sample_sharded)
 from repro.core.samplers.base import warmup
 from repro.launch.hlo_cost import analyze_compiled
+from repro.launch.mesh import auto_mesh_of
 from repro.models import build_model, init_params
 from repro.models.tame import tame_dit, tame_networks
 
@@ -243,7 +244,7 @@ def run(smoke: bool = False):
     keys = jax.vmap(jax.random.fold_in, (None, 0))(
         jax.random.PRNGKey(7), jnp.arange(B))
     scales = jnp.full((B,), 2.5)
-    data_mesh = jax.make_mesh((ndev,), ("data",))
+    data_mesh = auto_mesh_of((ndev,), ("data",), jax.devices())
     cfg_mesh = auto_cfg_mesh()
 
     out_d = sample_sharded(plan_g, den_g, xT, keys, mesh=data_mesh,
@@ -270,8 +271,7 @@ def run(smoke: bool = False):
     # per-device work: doubled-lane on half the devices vs the cfg mesh
     # over all of them — same global batch, the cfg axis is parallelism
     # the data axis cannot reach (2 lanes/request/device -> 1)
-    half = jax.make_mesh((ndev // 2,), ("data",),
-                         devices=jax.devices()[:ndev // 2])
+    half = auto_mesh_of((ndev // 2,), ("data",), jax.devices()[:ndev // 2])
     cond_s = jax.ShapeDtypeStruct((4,), jnp.float32)
     fl = {}
     for tag, mesh, cax in [("lane_doubled", half, None),

@@ -39,6 +39,7 @@ under CFG (one fused call over a doubled lane count).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import jax
@@ -142,6 +143,15 @@ class Denoiser:
         null_cond: the unconditional conditioning for CFG. ``None`` means
             "zeros like the per-call cond" (the common null-embedding
             convention when the null token is the zero vector).
+        params: the network's weights as a pytree of arrays, or ``None``
+            when ``network`` closes over its own. When set, ``network``
+            is called as ``network(params, x, t, cond)`` (and a cached
+            companion as ``cached.call(params, ...)``), and the executors
+            pass ``params`` to the compiled program as an argument. A
+            closed-over weight array is instead embedded in every
+            compiled executable as a constant: at published widths that
+            is gigabytes per executable, in the compile and on the
+            device.
 
     Identity semantics: ``eq=False`` keeps the dataclass hashable by
     object identity, and instances are weak-referenceable — the sampler
@@ -158,6 +168,7 @@ class Denoiser:
     #: optional feature-cached companion network; required when a sampler
     #: spec sets ``feature_cache`` (see CachedNetwork)
     cached: CachedNetwork | None = None
+    params: Any = None
 
     def __post_init__(self):
         object.__setattr__(
@@ -172,6 +183,19 @@ class Denoiser:
                 canonical_prediction(target), self.schedule)
 
     # ------------------------------------------------------------ binding
+    def bind(self, params) -> "Denoiser":
+        """This denoiser with ``params`` (traced, inside an executor)
+        folded into its networks; ``self`` when it carries no params."""
+        if self.params is None:
+            return self
+        cached = self.cached
+        if cached is not None:
+            cached = CachedNetwork(functools.partial(cached.call, params),
+                                   cached.init)
+        return dataclasses.replace(
+            self, network=functools.partial(self.network, params),
+            cached=cached, params=None)
+
     def _cfg_pair(self, x, cond, cfg_sharding):
         """Stack the cond/uncond lanes ([2] leading axis). When
         ``cfg_sharding`` names a mesh axis, constrain that axis onto it —

@@ -477,17 +477,25 @@ def _adapter_statics(plan: SamplerPlan, model_fn) -> tuple | None:
     return None
 
 
-def _bind_model(m, adapter, cond, scale, cfg_shard=None):
+def _model_params(model_fn):
+    """The weights a Denoiser hands its executors as an argument (None
+    for plain callables, which close over their own)."""
+    return model_fn.params if isinstance(model_fn, Denoiser) else None
+
+
+def _bind_model(m, adapter, cond, scale, cfg_shard=None, params=None):
     """Build the executor-facing ``model_fn(x, t)`` closure at trace time,
-    folding in the traced ``cond``/``scale`` arguments. When the model is
-    a Denoiser with a feature-cached companion, the closure additionally
-    carries ``cached_call(x, t, feats, refresh) -> (pred, feats)`` and
-    ``init_feats(x)`` attributes for feature-caching executors.
+    folding in the traced ``cond``/``scale``/``params`` arguments. When
+    the model is a Denoiser with a feature-cached companion, the closure
+    additionally carries ``cached_call(x, t, feats, refresh) -> (pred,
+    feats)`` and ``init_feats(x)`` attributes for feature-caching
+    executors.
     ``cfg_shard`` (a NamedSharding over the CFG axis) requests sharded
     classifier-free guidance inside the Denoiser."""
     if adapter is None:
         return m
     if adapter[0] == "denoiser":
+        m = m.bind(params)
         fn = m.as_model_fn(adapter[3], cond, scale, cfg_shard)
         if m.cached is not None:
             fn.cached_call = m.as_cached_model_fn(
@@ -678,20 +686,20 @@ def _compiled(plan: SamplerPlan, model_fn: ModelFn, shape, dtype,
     cell = [cell_ref if cell_ref is not None else model_fn]
 
     if batch is not None:
-        def run(arrays, xs, keys, cond, scale):
+        def run(arrays, xs, keys, cond, scale, params):
             m = _deref_model(cell)
             return jax.vmap(
                 lambda x, k, c, s: family.execute(
                     statics, arrays,
-                    _bind_model(m, adapter, c, s, cfg_shard), x, k,
+                    _bind_model(m, adapter, c, s, cfg_shard, params), x, k,
                     trajectory)
             )(xs, keys, cond, scale)
     else:
-        def run(arrays, x, k, cond, scale):
+        def run(arrays, x, k, cond, scale, params):
             m = _deref_model(cell)
             return family.execute(
                 statics, arrays,
-                _bind_model(m, adapter, cond, scale, cfg_shard),
+                _bind_model(m, adapter, cond, scale, cfg_shard, params),
                 x, k, trajectory)
 
     jit_kw: dict = {}
@@ -704,6 +712,7 @@ def _compiled(plan: SamplerPlan, model_fn: ModelFn, shape, dtype,
             lane,   # per-lane PRNG keys
             lane,   # cond pytree: leading request axis (prefix)
             lane,   # per-lane guidance scale
+            rep,    # model weights: replicated
         )
         if donate:
             jit_kw["donate_argnums"] = (1,)  # the x_T carry buffer
@@ -714,17 +723,17 @@ def _compiled(plan: SamplerPlan, model_fn: ModelFn, shape, dtype,
     return entry
 
 
-def _call(entry: _CacheEntry, arrays, x, k, cond, scale):
+def _call(entry: _CacheEntry, arrays, x, k, cond, scale, params):
     if entry.aot is not None:
         try:
-            return entry.aot(arrays, x, k, cond, scale)
+            return entry.aot(arrays, x, k, cond, scale, params)
         except TypeError:
             # aval mismatch vs the warmed bucket (e.g. a re-planned step
             # count changed the coefficient-table shapes, or a typed key
             # array): fall back to the jit wrapper, which retraces within
             # this entry; counted so the degradation is observable
             _CACHE_STATS["aot_fallbacks"] += 1
-    return entry.fn(arrays, x, k, cond, scale)
+    return entry.fn(arrays, x, k, cond, scale, params)
 
 
 def _default_donate() -> bool:
@@ -757,7 +766,8 @@ def sample(plan: SamplerPlan, model_fn: ModelFn, x_T: jnp.ndarray,
     cond, scale = _check_model(plan, model_fn, cond, guidance_scale)
     entry = _compiled(plan, model_fn, x_T.shape, x_T.dtype, trajectory,
                       None, model_key=model_key, cond=cond)
-    return _call(entry, plan.arrays, x_T, key, cond, scale)
+    return _call(entry, plan.arrays, x_T, key, cond, scale,
+                 _model_params(model_fn))
 
 
 def sample_batched(plan: SamplerPlan, model_fn: ModelFn, x_T: jnp.ndarray,
@@ -780,7 +790,8 @@ def sample_batched(plan: SamplerPlan, model_fn: ModelFn, x_T: jnp.ndarray,
     scale = jnp.broadcast_to(scale, (int(x_T.shape[0]),))
     entry = _compiled(plan, model_fn, x_T.shape[1:], x_T.dtype, trajectory,
                       int(x_T.shape[0]), model_key=model_key, cond=cond)
-    return _call(entry, plan.arrays, x_T, keys, cond, scale)
+    return _call(entry, plan.arrays, x_T, keys, cond, scale,
+                 _model_params(model_fn))
 
 
 def sample_sharded(plan: SamplerPlan, model_fn: ModelFn, x_T: jnp.ndarray,
@@ -830,7 +841,8 @@ def sample_sharded(plan: SamplerPlan, model_fn: ModelFn, x_T: jnp.ndarray,
                       int(x_T.shape[0]), model_key=model_key, mesh=mesh,
                       data_axis=data_axis, cfg_axis=cfg_axis,
                       donate=donate, cond=cond)
-    return _call(entry, plan.arrays, x_T, keys, cond, scale)
+    return _call(entry, plan.arrays, x_T, keys, cond, scale,
+                 _model_params(model_fn))
 
 
 def warmup(plan: SamplerPlan, model_fn: ModelFn, shape, dtype=jnp.float32,
@@ -868,8 +880,9 @@ def warmup(plan: SamplerPlan, model_fn: ModelFn, shape, dtype=jnp.float32,
                       data_axis=data_axis, cfg_axis=cfg_axis,
                       donate=bool(donate), cond=cond_s)
     if entry.aot is None:
-        arrays_s = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), plan.arrays)
+        aval = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+        arrays_s = jax.tree.map(aval, plan.arrays)
+        params_s = jax.tree.map(aval, _model_params(model_fn))
         # key aval follows the configured PRNG impl (threefry: (2,) u32,
         # rbg: (4,) u32) — hardcoding would silently strand the AOT
         # executable behind _call's jit fallback
@@ -883,7 +896,8 @@ def warmup(plan: SamplerPlan, model_fn: ModelFn, shape, dtype=jnp.float32,
             x_s = jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
             k_s = jax.ShapeDtypeStruct(proto.shape, proto.dtype)
             s_s = jax.ShapeDtypeStruct((), jnp.float32)
-        entry.aot = entry.fn.lower(arrays_s, x_s, k_s, cond_s, s_s).compile()
+        entry.aot = entry.fn.lower(arrays_s, x_s, k_s, cond_s, s_s,
+                                   params_s).compile()
     return entry.aot
 
 

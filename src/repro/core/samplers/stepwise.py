@@ -83,8 +83,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .base import (SamplerPlan, _adapter_statics, _bind_model,
-                   _check_model, _deref_model, _model_token, _weak,
-                   carry_dtype, cond_struct, get_family)
+                   _check_model, _deref_model, _model_params, _model_token,
+                   _weak, carry_dtype, cond_struct, get_family)
 
 __all__ = [
     "StepAdapter",
@@ -213,7 +213,7 @@ def fresh_carry(plan: SamplerPlan, batch: int, shape, dtype,
 # ------------------------------------------------------------ compile cache
 _STEP_CACHE: OrderedDict = OrderedDict()
 _STEP_CACHE_MAX = 64
-_STEP_STATS = {"hits": 0, "misses": 0, "evictions": 0}
+_STEP_STATS = {"hits": 0, "misses": 0, "evictions": 0, "aot_fallbacks": 0}
 _STEP_TOKEN_IDX = 7  # position of the model token inside a step key
 
 
@@ -267,11 +267,14 @@ class StepFns:
             try:
                 return aot(*args)
             except TypeError:
-                pass  # aval drift vs the warmed shapes: jit fallback
+                # aval drift vs the warmed shapes: jit fallback, counted
+                # so the degradation is observable
+                _STEP_STATS["aot_fallbacks"] += 1
         return fn(*args)
 
     def step(self, arrays, carry):
-        return self._call(self._aot_step, self._step, arrays, carry)
+        params = _model_params(_deref_model(self.cell))
+        return self._call(self._aot_step, self._step, arrays, carry, params)
 
     def join(self, arrays, carry, lane, x_T, keys, tol, min_i, scale,
              guard=0, cond=None):
@@ -304,7 +307,9 @@ class StepFns:
             lambda a: jax.ShapeDtypeStruct(tuple(a.shape),
                                            jnp.dtype(a.dtype)), t)
         arrays_s, carry_s = aval(arrays), aval(carry)
-        self._aot_step = self._step.lower(arrays_s, carry_s).compile()
+        params_s = aval(_model_params(_deref_model(self.cell)))
+        self._aot_step = self._step.lower(arrays_s, carry_s,
+                                          params_s).compile()
         proto = jax.random.PRNGKey(0)
         M = carry["keys"].shape[1]
         i_s = jax.ShapeDtypeStruct((), jnp.int32)
@@ -325,13 +330,13 @@ class StepFns:
 
 def _make_run_step(adapter, dadapter, cell, has_cond: bool, stream: bool,
                    has_fc: bool = False):
-    def run_step(arrays, carry):
+    def run_step(arrays, carry, params):
         m = _deref_model(cell)
         M = adapter.n_steps_of(arrays)
 
         def lane(inner, i, keys, active, x_final, err_prev, tol, min_i,
                  scale, guard, cond, feats):
-            model = _bind_model(m, dadapter, cond, scale)
+            model = _bind_model(m, dadapter, cond, scale, params=params)
             init = i < 0
             ic = jnp.clip(i, 0, M - 1)
             if has_fc:

@@ -86,8 +86,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 @functools.partial(jax.jit,
                    static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 512,
-                    bk: int = 512, interpret: bool = True):
+                    bk: int = 512, interpret: bool | None = None):
     """q [B,H,S,hd]; k,v [B,K,T,hd], K | H. Returns [B,H,S,hd] in q.dtype.
+
+    ``interpret=None`` auto-detects the backend like ``sa_update``:
+    compiled Mosaic on TPU, the Pallas interpreter elsewhere.
 
     Ragged (non-block-multiple) S/T are handled by zero-padding up to the
     block grid and masking: padded key positions get ``NEG_INF`` scores
@@ -95,6 +98,8 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 512,
     rows are sliced off the output. Block-multiple shapes skip the
     padding entirely and trace the exact unpadded graph.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     B, H, S, hd = q.shape
     K, T = k.shape[1], k.shape[2]
     G = H // K

@@ -50,13 +50,11 @@ def flash_attention(q, k, v, *, causal: bool = True, mode: str = "auto",
                     bq: int = 512, bk: int = 512):
     if mode == "jnp" or (mode == "auto" and not on_tpu()):
         return ref.flash_attention_ref(q, k, v, causal=causal)
-    return _flash_kernel(q, k, v, causal=causal, bq=bq, bk=bk,
-                         interpret=not on_tpu())
+    return _flash_kernel(q, k, v, causal=causal, bq=bq, bk=bk)
 
 
 def wkv(r, k, v, logw, u, S0, *, chunk: int = 64, mode: str = "auto"):
     if mode == "jnp" or (mode == "auto" and not on_tpu()):
         from ..models.rwkv6 import wkv_chunked
         return wkv_chunked(r, k, v, logw, u, S0, chunk)
-    return _wkv_kernel(r, k, v, logw, u, S0, chunk=chunk,
-                       interpret=not on_tpu())
+    return _wkv_kernel(r, k, v, logw, u, S0, chunk=chunk)

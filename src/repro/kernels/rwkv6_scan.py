@@ -72,9 +72,13 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sout_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rwkv6_wkv(r, k, v, logw, u, S0, *, chunk: int = 64,
-              interpret: bool = True):
+              interpret: bool | None = None):
     """r/k/v/logw [B,T,H,hd]; u [H,hd]; S0 [B,H,hd,hd].
-    Returns (y [B,T,H,hd] f32, S_T [B,H,hd,hd] f32)."""
+    Returns (y [B,T,H,hd] f32, S_T [B,H,hd,hd] f32).
+
+    ``interpret=None`` auto-detects the backend like ``sa_update``."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     B, T, H, hd = r.shape
     if T % chunk:
         raise ValueError(f"T={T} % chunk={chunk} != 0")

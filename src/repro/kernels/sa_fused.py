@@ -20,41 +20,21 @@ Coefficients arrive as one f32 matrix [2, P+2], each row packed in the
 ``sa_update`` convention (decay, noise, b_0..b_{P-1}); row 0 is the
 predictor, row 1 the corrector. With a ring-buffer history the caller
 rotates the *coefficient columns* by the ring head — the [P, N] data is
-never rotated or re-stacked (see ``samplers/sa.py``).
+never rotated or re-stacked (see ``samplers/multistep.py``).
 
-Tiling mirrors ``sa_update``: ``choose_tile`` picks a lane-aligned tile
-dividing n (masked ragged final block otherwise), so scan-step calls are
+Layout and tiling are ``sa_update``'s (the same kernel with two
+coefficient rows): ``[n/128, 128]`` rows in sublane-aligned blocks, so the
+kernel compiles under the serving lanes' ``vmap`` and scan-step calls are
 copy-free.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
-from .sa_update import DEFAULT_TILE, choose_tile, lane_align
+from .sa_update import DEFAULT_TILE, sa_combine
 
 __all__ = ["sa_fused_update"]
 
 
-def _kernel(coeff_ref, x_ref, buf_ref, xi_ref, pred_ref, corr_ref, *,
-            P: int):
-    x = x_ref[...].astype(jnp.float32)
-    xi = xi_ref[...].astype(jnp.float32)
-    acc_p = coeff_ref[0, 0] * x + coeff_ref[0, 1] * xi
-    acc_c = coeff_ref[1, 0] * x + coeff_ref[1, 1] * xi
-    for j in range(P):  # unrolled: P is static and small (<= 5)
-        bj = buf_ref[j, :].astype(jnp.float32)
-        acc_p = acc_p + coeff_ref[0, 2 + j] * bj
-        acc_c = acc_c + coeff_ref[1, 2 + j] * bj
-    pred_ref[...] = acc_p.astype(pred_ref.dtype)
-    corr_ref[...] = acc_c.astype(corr_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def sa_fused_update(x, buf, xi, coeffs, *, tile: int = DEFAULT_TILE,
                     interpret: bool | None = None):
     """x [*shape]; buf [P, *shape]; xi [*shape]; coeffs [2, P+2] f32,
@@ -63,29 +43,4 @@ def sa_fused_update(x, buf, xi, coeffs, *, tile: int = DEFAULT_TILE,
 
     ``interpret=None`` auto-detects the backend like ``sa_update``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    shape = x.shape
-    P = buf.shape[0]
-    n = x.size
-    xf = x.reshape(n)
-    xif = xi.reshape(n)
-    buff = buf.reshape(P, n)
-    t = choose_tile(n, tile, lane_align(x.dtype))
-    grid = (pl.cdiv(n, t),)
-    out_tile = pl.BlockSpec((t,), lambda i: (i,))
-    pred, corr = pl.pallas_call(
-        functools.partial(_kernel, P=P),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((2, P + 2), lambda i: (0, 0)),  # coeffs: broadcast
-            pl.BlockSpec((t,), lambda i: (i,)),          # x tile
-            pl.BlockSpec((P, t), lambda i: (0, i)),      # buffer tile stack
-            pl.BlockSpec((t,), lambda i: (i,)),          # xi tile
-        ],
-        out_specs=[out_tile, out_tile],
-        out_shape=[jax.ShapeDtypeStruct((n,), x.dtype),
-                   jax.ShapeDtypeStruct((n,), x.dtype)],
-        interpret=interpret,
-    )(coeffs.astype(jnp.float32), xf, buff, xif)
-    return pred.reshape(shape), corr.reshape(shape)
+    return sa_combine(x, buf, xi, coeffs, tile=tile, interpret=interpret)

@@ -17,7 +17,16 @@ import math
 import jax
 import numpy as np
 
-__all__ = ["make_production_mesh", "make_test_mesh"]
+__all__ = ["auto_mesh_of", "make_production_mesh", "make_test_mesh"]
+
+
+def auto_mesh_of(shape, axes, devices):
+    """``jax.make_mesh`` with every axis ``Auto``. Since JAX 0.9 the
+    default is ``Explicit`` axes, on which ``with_sharding_constraint``
+    (sharded CFG) and GSPMD propagation are refused; the serving and
+    production meshes rely on both."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -31,10 +40,10 @@ def make_production_mesh(*, multi_pod: bool = False):
             "run under XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "(launch/dryrun.py sets this)"
         )
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return auto_mesh_of(shape, axes, devs[:need])
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for in-container multi-device tests (8 fake devices)."""
     need = math.prod(shape)
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:need])
+    return auto_mesh_of(shape, axes, jax.devices()[:need])

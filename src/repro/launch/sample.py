@@ -59,13 +59,14 @@ def build_denoiser(arch: str, smoke: bool, latent: int | None):
     return cfg, model, params
 
 
-def as_prediction_network(model, params, schedule, prediction: str):
+def as_prediction_network(model, schedule, prediction: str):
     """Re-express an x0-prediction backbone as an eps/x0/v network with a
-    cond input — the ``(x, t, cond) -> prediction`` contract Denoiser
-    wraps. ``cond`` (when given) is an input-space prompt added to the
-    latent; the output is converted in-graph to ``prediction``."""
+    cond input — the ``(params, x, t, cond) -> prediction`` contract a
+    Denoiser built with ``params=`` wraps. ``cond`` (when given) is an
+    input-space prompt added to the latent; the output is converted
+    in-graph to ``prediction``."""
 
-    def network(x, t, cond):
+    def network(params, x, t, cond):
         h = x if cond is None else x + cond
         # per-lane executors (sample_batched / sample_sharded / serve)
         # call with an unbatched [S, dz] latent — re-rank for the model
@@ -77,7 +78,7 @@ def as_prediction_network(model, params, schedule, prediction: str):
     return network
 
 
-def as_cached_network(model, params, schedule, prediction: str):
+def as_cached_network(model, schedule, prediction: str):
     """The feature-cached twin of :func:`as_prediction_network`: a
     :class:`CachedNetwork` whose ``call`` threads the mid-block feature
     pytree through ``model.denoise_cached`` and whose ``init`` builds the
@@ -89,7 +90,7 @@ def as_cached_network(model, params, schedule, prediction: str):
                 f"--feature-cache needs a backbone with {attr}(); "
                 f"{type(model).__name__} has none")
 
-    def call(x, t, cond, feats, refresh):
+    def call(params, x, t, cond, feats, refresh):
         h = x if cond is None else x + cond
         lane = h.ndim == 2
         x0, new = model.denoise_cached(
@@ -216,20 +217,21 @@ def main():
     if args.cond_file is not None:
         cond = jnp.asarray(np.load(args.cond_file), jnp.float32)
     model_fn = Denoiser(
-        as_prediction_network(model, params, schedule, args.prediction),
+        as_prediction_network(model, schedule, args.prediction),
         schedule, prediction=args.prediction, guidance=guidance,
-        cached=(as_cached_network(model, params, schedule, args.prediction)
-                if fc is not None else None))
+        cached=(as_cached_network(model, schedule, args.prediction)
+                if fc is not None else None),
+        params=params)
 
     mesh = None
     if args.cfg_shard:
         from ..serve.sharding import auto_cfg_mesh
         if not guidance:
             raise SystemExit("--cfg-shard needs --guidance-scale")
-        mesh = auto_cfg_mesh()
-        if mesh is None:
-            raise SystemExit("--cfg-shard needs an even device count >= 2 "
-                             f"(have {len(jax.devices())})")
+        try:
+            mesh = auto_cfg_mesh()
+        except ValueError as e:
+            raise SystemExit(f"--cfg-shard: {e}")
 
     xT = sampler.init_noise(jax.random.PRNGKey(1), (args.batch, args.seq, dz))
 

@@ -5,25 +5,27 @@
         --arch rwkv6-3b --smoke --batch 4 --prompt-len 32 --gen 16
 
     # Diffusion serving (the repro.serve engine: plan-keyed microbatching,
-    # AOT-warmed buckets, optional mesh sharding + preview streaming):
-    PYTHONPATH=src python -m repro.launch.serve --mode diffusion \
+    # AOT-warmed buckets, optional mesh sharding + preview streaming);
+    # --smoke swaps in the 2-layer config (CPU), without it the arch
+    # runs at its published widths:
+    PYTHONPATH=src python -m repro.launch.serve --mode diffusion --smoke \
         --arch dit-s --sampler sa --requests 12 --nfe 15 --tau 0.6 --stream
 
     # ... serving the backbone as a v-prediction checkpoint under
     # classifier-free guidance (denoiser adapter; scale is traced data):
-    PYTHONPATH=src python -m repro.launch.serve --mode diffusion \
+    PYTHONPATH=src python -m repro.launch.serve --mode diffusion --smoke \
         --arch dit-s --prediction v --guidance-scale 3.0 --requests 8
 
     # ... with step-granular continuous batching — requests join and
     # leave running lane groups at step boundaries, and a masked early
     # exit retires converged lanes under the fixed compiled shape:
-    PYTHONPATH=src python -m repro.launch.serve --mode diffusion \
+    PYTHONPATH=src python -m repro.launch.serve --mode diffusion --smoke \
         --scheduler step --lanes 8 --early-exit-tol 0.02 --requests 12
 
     # ... by quality tier — draft/standard/best resolve to step programs
     # at submit time; --tuned-artifact loads an autotuner winner
     # (python -m repro.launch.tune) as the "best" tier:
-    PYTHONPATH=src python -m repro.launch.serve --mode diffusion \
+    PYTHONPATH=src python -m repro.launch.serve --mode diffusion --smoke \
         --quality-tier best --tuned-artifact artifacts/tune_nfe8.json
 
 ``--mode lm`` runs a real (reduced-config on CPU) decode loop: prefill
@@ -33,18 +35,41 @@ lowers at full scale. ``--mode diffusion`` drives
 :class:`repro.serve.ServeEngine` over any registered sampler; with
 ``--sharded`` the request axis rides the ``data`` axis of a mesh over all
 visible devices (run under
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to try it on CPU).
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to try it on CPU);
+with one device it is an error. The CLI exits non-zero when any
+request ends not-ok, unless ``--inject`` asked for faults.
+
+JAX's persistent compilation cache lives where
+``JAX_COMPILATION_CACHE_DIR`` says, else at the fixed ``<repo>/.jax_cache``.
 """
 
 import argparse
 import dataclasses
+import math
+import os
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 
 from ..configs import get_config, get_smoke
 from ..models import build_model, init_params
+
+#: the checkout root (src/repro/launch/serve.py -> three levels up)
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its path:
+    ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads it itself, so
+    nothing is configured here), else the fixed ``<repo>/.jax_cache`` —
+    a fixed path, because a cache that moves never hits."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def serve_lm(args) -> None:
@@ -108,28 +133,38 @@ def build_denoiser_model_fn(arch: str, latent: int | None, smoke: bool):
 
 
 def build_denoiser_network(arch: str, latent: int | None, smoke: bool,
-                           schedule, prediction: str):
-    """(cfg, Denoiser-contract network) — the per-request backbone
-    re-expressed as an eps/x0/v ``(x, t, cond)`` network, with ``cond``
-    consumed as an input-space prompt (the zoo backbones are
-    unconditional)."""
+                           schedule, prediction: str, *,
+                           weight_noise: float = 0.0):
+    """(cfg, network, params) — the backbone re-expressed as an eps/x0/v
+    ``(params, x, t, cond)`` network for ``Denoiser(..., params=params)``,
+    with ``cond`` consumed as an input-space prompt (the zoo backbones
+    are unconditional). Weights are seeded f32. ``weight_noise`` adds
+    seeded Gaussian noise of std ``weight_noise / sqrt(fan_in)`` to every
+    weight (the fan-in rule of the ``scaled`` init, so the noise's gain
+    does not grow with width), so the zero-init output projection and
+    adaLN gates carry signal on random weights."""
     from .sample import as_prediction_network
     cfg = get_smoke(arch) if smoke else get_config(arch)
     if getattr(cfg, "denoiser_latent", None) is None:
         cfg = dataclasses.replace(cfg, denoiser_latent=latent or 8)
     model = build_model(cfg)
-    params = init_params(jax.random.PRNGKey(0), model.param_defs(),
-                         jnp.float32)
 
-    class _PerRequest:
-        """Backbone view that re-adds the batch axis per request."""
+    def make(key, noise_key):
+        params = init_params(key, model.param_defs(), jnp.float32)
+        if not weight_noise:
+            return params
+        leaves, treedef = jax.tree.flatten(params)
+        keys = jax.random.split(noise_key, len(leaves))
+        return treedef.unflatten([
+            p + weight_noise / math.sqrt(p.shape[-2] if p.ndim >= 2
+                                         else p.shape[-1])
+            * jax.random.normal(k, p.shape, p.dtype)
+            for p, k in zip(leaves, keys)])
 
-        @staticmethod
-        def denoise(p, x, t):
-            return model.denoise(p, x[None], t)[0]
-
-    return cfg, as_prediction_network(_PerRequest, params, schedule,
-                                      prediction)
+    # one compiled program: op by op, every leaf's init is its own
+    # dispatch and compile (79 s for DiT-XL/2 on a v5e)
+    params = jax.jit(make)(jax.random.PRNGKey(0), jax.random.PRNGKey(1))
+    return cfg, as_prediction_network(model, schedule, prediction), params
 
 
 def serve_diffusion(args) -> None:
@@ -142,26 +177,21 @@ def serve_diffusion(args) -> None:
 
     from ..serve.faults import FaultInjector, FaultPlan
 
+    mesh = None
+    if args.sharded:
+        try:
+            mesh = auto_mesh()
+        except ValueError as e:
+            raise SystemExit(f"--sharded: {e}")
     schedule = get_schedule("vp_linear")
     guidance = args.guidance_scale is not None
-    adapted = guidance or args.prediction != "data" \
-        or args.cond_file is not None
-    if adapted:
-        cfg, network = build_denoiser_network(
-            args.arch, args.latent, True, schedule, args.prediction)
-        model_fn = Denoiser(network, schedule, prediction=args.prediction,
-                            guidance=guidance)
-    else:
-        cfg, model_fn = build_denoiser_model_fn(args.arch, args.latent,
-                                                smoke=True)
+    cfg, network, params = build_denoiser_network(
+        args.arch, args.latent, args.smoke, schedule, args.prediction)
+    model_fn = Denoiser(network, schedule, prediction=args.prediction,
+                        guidance=guidance, params=params)
     cond = None
     if args.cond_file is not None:
         cond = jnp.asarray(np.load(args.cond_file), jnp.float32)
-    mesh = auto_mesh() if args.sharded else None
-    if args.sharded and mesh is None:
-        print("--sharded: only one device visible, falling back to the "
-              "unsharded path (set XLA_FLAGS="
-              "--xla_force_host_platform_device_count=8 to fake a mesh)")
 
     def show(res):
         if res.previews is not None:
@@ -174,11 +204,12 @@ def serve_diffusion(args) -> None:
         tiers = QualityTiers.from_artifact(args.tuned_artifact) \
             if args.tuned_artifact else default_tiers(
                 family=args.tier_family, schedule=schedule)
-        if adapted:  # tiers carry solver choices; serving adapter fields
-            tiers = QualityTiers({  # (prediction/guidance) come from flags
-                name: dataclasses.replace(
-                    s, prediction=args.prediction, guidance=guidance)
-                for name, s in tiers.specs.items()})
+        # tiers carry solver choices; the adapter fields
+        # (prediction/guidance) come from the flags
+        tiers = QualityTiers({
+            name: dataclasses.replace(
+                s, prediction=args.prediction, guidance=guidance)
+            for name, s in tiers.specs.items()})
     injector = None
     if args.inject and not args.guard_interval:
         args.guard_interval = 4  # injecting NaNs without the guard
@@ -205,8 +236,7 @@ def serve_diffusion(args) -> None:
         spec = SamplerSpec.from_nfe(
             args.sampler, args.nfe, schedule=schedule,
             predictor_order=3, corrector_order=1, tau=args.tau,
-            prediction=args.prediction if adapted else None,
-            guidance=guidance)
+            prediction=args.prediction, guidance=guidance)
         submit_kw = {}
     shape = (args.seq, cfg.denoiser_latent)
     g_scale = 1.0 if args.guidance_scale is None else args.guidance_scale
@@ -221,10 +251,11 @@ def serve_diffusion(args) -> None:
 
     results = engine.run()
     assert len(results) == args.requests
-    for res in results:
-        if getattr(res, "status", "ok") == "ok":
-            assert bool(jnp.all(jnp.isfinite(res.x0)))
-    bad = [r for r in results if getattr(r, "status", "ok") != "ok"]
+    nonfinite = [r.rid for r in results if r.status == "ok"
+                 and not bool(jnp.all(jnp.isfinite(r.x0)))]
+    if nonfinite:
+        print(f"non-finite x0 in 'ok' results: rids {nonfinite}")
+    bad = [r for r in results if r.status != "ok"]
     if bad or args.inject:
         h = engine.health()
         print(f"health: {h['status']} (completed={h['completed']}, "
@@ -270,6 +301,9 @@ def serve_diffusion(args) -> None:
               f"prediction={args.prediction}, "
               f"guidance={args.guidance_scale if guidance else 'off'})")
         print("compile cache:", s["compile_cache"])
+    if nonfinite or (bad and not args.inject):
+        raise SystemExit(f"{len(bad) + len(nonfinite)} of {len(results)} "
+                         "requests ended not-ok (see above)")
 
 
 def main():
@@ -278,7 +312,9 @@ def main():
     ap.add_argument("--arch", default=None,
                     help="zoo member (default: starcoder2-3b for lm, "
                     "dit-s for diffusion)")
-    ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--smoke", action="store_true",
+                    help="the arch's reduced test config instead of its "
+                    "published widths")
     ap.add_argument("--batch", type=int, default=4)
     # lm
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -348,6 +384,7 @@ def main():
                     "raised tick, 1 latency spike) through the serve "
                     "path; implies --guard-interval 4 if unset")
     args = ap.parse_args()
+    use_compile_cache()
     if args.arch is None:
         args.arch = "starcoder2-3b" if args.mode == "lm" else "dit-s"
     if args.mode == "lm":

@@ -17,6 +17,8 @@ from typing import Sequence
 
 import jax
 
+from ..launch.mesh import auto_mesh_of
+
 __all__ = ["data_axis_size", "align_bucket_sizes", "auto_mesh",
            "auto_cfg_mesh"]
 
@@ -44,16 +46,18 @@ def align_bucket_sizes(bucket_sizes: Sequence[int], n_data: int) -> tuple:
 def auto_mesh(data_axis: str = "data"):
     """A serving mesh over all visible devices: ``(data=n, model=1)``.
 
-    Returns None on a single device (the engine then runs the unsharded
-    ``sample_batched`` path). Real deployments pass an explicit mesh
+    Raises on a single device: a caller that asked for sharding must not
+    silently get the unsharded path (pass ``mesh=None`` to the engine for
+    that). Real deployments pass an explicit mesh
     (``make_production_mesh``) so the model axis is sized for the
     backbone's tensor parallelism instead.
     """
     n = len(jax.devices())
     if n <= 1:
-        return None
-    return jax.make_mesh((n, 1), (data_axis, "model"),
-                         devices=jax.devices())
+        raise ValueError(
+            f"a sharded serving mesh needs >= 2 devices, have {n} "
+            f"({jax.devices()[0].platform})")
+    return auto_mesh_of((n, 1), (data_axis, "model"), jax.devices())
 
 
 def auto_cfg_mesh(data_axis: str = "data", cfg_axis: str = "cfg"):
@@ -62,13 +66,14 @@ def auto_cfg_mesh(data_axis: str = "data", cfg_axis: str = "cfg"):
     Sharded classifier-free guidance places the cond/uncond pair on the
     size-2 ``cfg`` axis — each device evaluates ONE branch at the local
     batch instead of both at a doubled local batch — and the request
-    axis on the remaining ``data`` factor. Returns None when there are
-    fewer than two (or an odd number of) devices; the engine then falls
-    back to the fused doubled-lane eval, which is numerically the same
+    axis on the remaining ``data`` factor. Raises when there are fewer
+    than two (or an odd number of) devices; without a mesh the engine
+    runs the fused doubled-lane eval, which is numerically the same
     combine.
     """
     n = len(jax.devices())
     if n < 2 or n % 2:
-        return None
-    return jax.make_mesh((2, n // 2), (cfg_axis, data_axis),
-                         devices=jax.devices())
+        raise ValueError(
+            f"a CFG-sharded mesh needs an even device count >= 2, have {n} "
+            f"({jax.devices()[0].platform})")
+    return auto_mesh_of((2, n // 2), (cfg_axis, data_axis), jax.devices())

@@ -393,3 +393,58 @@ def test_sa_pec_corrector_preview_reconstructs_from_eval_state():
             np.asarray(traj["x0"][i]), np.asarray(want), rtol=5e-3,
             atol=5e-3, err_msg=f"preview at step {i} is not the x0 "
             "posterior at the evaluated state")
+
+
+# ------------------------------------------- weights as an argument
+def _weighted_net(p, x, t, cond):
+    h = x if cond is None else x + cond
+    return 0.5 * jnp.tanh(h @ p["w"]) @ p["v"]
+
+
+def _weights(seed):
+    k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
+    return {"w": 0.3 * jax.random.normal(k1, (2, 64)),
+            "v": 0.3 * jax.random.normal(k2, (64, 2))}
+
+
+def test_params_are_an_executor_argument():
+    """A Denoiser built with ``params=`` hands its weights to the compiled
+    executor as an argument: new weights under one model_key reuse the
+    executable (one miss, one hit) and change the result, and the result
+    equals the same network closing over its weights."""
+    spec = SamplerSpec(name="sa", schedule=SCHED, n_steps=6, tau=0.5,
+                       guidance=True)
+    keys = jax.random.split(KEY, 4)
+    xs = XT[:64].reshape(4, 16, 2)
+    cond = jnp.broadcast_to(COND, (4, 2))
+    p1, p2 = _weights(1), _weights(2)
+    run = lambda den, **kw: sample_batched(
+        build_plan(spec), den, xs, keys, cond=cond, guidance_scale=2.0,
+        **kw)
+    clear_compile_cache()
+    a = run(Denoiser(_weighted_net, SCHED, "x0", True, params=p1),
+            model_key="weights-arg")
+    b = run(Denoiser(_weighted_net, SCHED, "x0", True, params=p2),
+            model_key="weights-arg")
+    stats = compile_cache_stats()
+    assert stats["misses"] == 1 and stats["hits"] == 1, stats
+    assert not bool(jnp.all(a == b))
+    closed = Denoiser(lambda x, t, c: _weighted_net(p1, x, t, c), SCHED,
+                      "x0", True)
+    np.testing.assert_allclose(np.asarray(run(closed)), np.asarray(a),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_params_denoiser_serves_on_both_schedulers():
+    """The step scheduler's compiled step function takes the weights as
+    an argument too, and serves what the solve scheduler serves."""
+    spec = SamplerSpec(name="sa", schedule=SCHED, n_steps=6, tau=0.5,
+                       guidance=True)
+    den = Denoiser(_weighted_net, SCHED, "x0", True, params=_weights(3))
+    submits = [(r, COND, 1.5) for r in range(3)]
+    solve = serve_rids(ServeEngine(den, bucket_sizes=(4,)), submits, spec,
+                       (16, 2))
+    step = serve_rids(ServeEngine(den, scheduler="step", lanes=2), submits,
+                      spec, (16, 2))
+    for r in solve:
+        np.testing.assert_allclose(step[r], solve[r], rtol=1e-5, atol=1e-5)

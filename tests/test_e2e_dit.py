@@ -194,6 +194,7 @@ from repro.configs import get_smoke
 from repro.core import Denoiser, get_schedule
 from repro.core.samplers import SamplerSpec, Sampler
 from repro.models import build_model, init_params
+from repro.launch.mesh import auto_mesh_of
 from repro.serve.sharding import auto_cfg_mesh
 
 ndev = len(jax.devices())
@@ -223,7 +224,7 @@ cond = jnp.ones((B, 4), jnp.float32)
 xT = Sampler(spec_g).init_noise(jax.random.PRNGKey(5), (B, S, dz))
 keys = jax.vmap(jax.random.fold_in, (None, 0))(jax.random.PRNGKey(7),
                                                jnp.arange(B))
-data = jax.make_mesh((ndev,), ("data",))
+data = auto_mesh_of((ndev,), ("data",), jax.devices())
 cfgm = auto_cfg_mesh()
 assert cfgm is not None and cfgm.devices.shape == (2, ndev // 2)
 
