@@ -102,25 +102,28 @@ def _sdpa(q, k, v, *, causal: bool, q_offset=0, kv_len=None, softcap=None,
     """q [B,S,H,hd]; k,v [B,T,K,hd]. Dispatcher: q-chunked via lax.map for
     long sequences (bounds live attention scores to [B,H,q_chunk,T] —
     the jnp stand-in for the flash kernel's blocking; XLA frees each chunk
-    before the next because lax.map is sequential), direct otherwise."""
-    B, S, H, hd = q.shape
-    if S > q_chunk and S % q_chunk == 0:
-        n = S // q_chunk
-        qc = jnp.swapaxes(q.reshape(B, n, q_chunk, H, hd), 0, 1)
-        offs = q_offset + jnp.arange(n) * q_chunk
+    before the next because lax.map is sequential), direct otherwise.
+    The scores, softmax and weighted values run under
+    ``named_scope("attention")``, which names their ops in a profile."""
+    with jax.named_scope("attention"):
+        B, S, H, hd = q.shape
+        if S > q_chunk and S % q_chunk == 0:
+            n = S // q_chunk
+            qc = jnp.swapaxes(q.reshape(B, n, q_chunk, H, hd), 0, 1)
+            offs = q_offset + jnp.arange(n) * q_chunk
 
-        @jax.checkpoint
-        def one(args):
-            # checkpointed: map-backward saves only the chunk inputs, not
-            # the [B,H,chunk,T] softmax residuals of every chunk at once
-            qi, off = args
-            return _sdpa_block(qi, k, v, causal=causal, q_offset=off,
-                               kv_len=kv_len, softcap=softcap)
+            @jax.checkpoint
+            def one(args):
+                # checkpointed: map-backward saves only the chunk inputs, not
+                # the [B,H,chunk,T] softmax residuals of every chunk at once
+                qi, off = args
+                return _sdpa_block(qi, k, v, causal=causal, q_offset=off,
+                                   kv_len=kv_len, softcap=softcap)
 
-        out = jax.lax.map(one, (qc, offs))
-        return jnp.swapaxes(out, 0, 1).reshape(B, S, H, v.shape[-1])
-    return _sdpa_block(q, k, v, causal=causal, q_offset=q_offset,
-                       kv_len=kv_len, softcap=softcap)
+            out = jax.lax.map(one, (qc, offs))
+            return jnp.swapaxes(out, 0, 1).reshape(B, S, H, v.shape[-1])
+        return _sdpa_block(q, k, v, causal=causal, q_offset=q_offset,
+                           kv_len=kv_len, softcap=softcap)
 
 
 def _sdpa_block(q, k, v, *, causal: bool, q_offset=0, kv_len=None, softcap=None):

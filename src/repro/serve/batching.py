@@ -102,6 +102,10 @@ class Request:
     #: label of the degradation-ladder rung this retry runs at (a tier
     #: name or "tau0"); None while undegraded
     degraded_to: str | None = None
+    #: ``time.monotonic()`` of the request's first enqueue (set by the
+    #: step scheduler's ``enqueue``; a retry keeps it), so a join can
+    #: report how long the request queued
+    enqueued: float | None = None
 
 
 def bucket_key(req: Request) -> tuple:

@@ -44,6 +44,14 @@ shape). ``stats()["buckets"]`` reports per-bucket occupancy; the
 solve-granular engine reports the same shape of numbers, so
 ``benchmarks/bench_continuous.py`` compares the two schedulers
 like-for-like.
+
+Each tick is a ``serve.tick`` profiler span (``jax.profiler``'s
+``TraceAnnotation``, a no-op with no profiler session) with children
+``serve.admit`` (holding ``serve.new_batch`` and ``serve.join``, whose
+arguments are the rid and ``queued_s``, the seconds since the request's
+first enqueue), ``serve.dispatch``, ``serve.sync`` (the host blocked on
+the device), ``serve.harvest`` and ``serve.merge``. They land on the
+profiler's host plane, on the clock of the device ops.
 """
 
 from __future__ import annotations
@@ -55,6 +63,7 @@ from typing import Callable, Hashable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from ..core.denoiser import Denoiser
 from ..core.samplers import (SamplerSpec, build_plan, fresh_carry,
@@ -191,6 +200,8 @@ class ContinuousBatcher:
                 f"admission control: {len(self._pending)} requests "
                 f"pending >= max_pending={self.max_pending}; drain with "
                 "tick()/run() or shed load upstream")
+        if req.enqueued is None:
+            req = dataclasses.replace(req, enqueued=time.monotonic())
         dl = float("inf") if req.deadline is None else float(req.deadline)
         self._pending.append(((-int(req.priority), dl, self._seq), req))
         self._seq += 1
@@ -304,26 +315,27 @@ class ContinuousBatcher:
             error=f"{type(err).__name__}: {err}"))]
 
     def _new_batch(self, req: Request) -> RunningBatch:
-        key = bucket_key(req)
-        spec = key[0]
-        plan = build_plan(spec)
-        fns = make_stepfns(plan, self.model_fn, req.shape, req.dtype,
-                           self.lanes, cond=req.cond,
-                           guidance_scale=req.guidance_scale,
-                           stream=self.stream, model_key=self.model_key)
-        arrays = fns.adapter.arrays(plan)
-        carry = fresh_carry(plan, self.lanes, req.shape, req.dtype,
-                            cond=req.cond, model_fn=self.model_fn,
-                            guard_every=self.guard_interval)
-        if not fns.warmed:
-            fns.warm(arrays, carry, cond=req.cond)
-            self._stats["warmups"] += 1
-        scale = spec.resolve_schedule().prior_scale(float(plan.ts[0]))
-        M = fns.adapter.n_steps_of(arrays)
-        batch = RunningBatch(key, plan, fns, arrays, carry, self.lanes,
-                             scale, M)
-        self._batches.append(batch)
-        return batch
+        with span("serve.new_batch"):
+            key = bucket_key(req)
+            spec = key[0]
+            plan = build_plan(spec)
+            fns = make_stepfns(plan, self.model_fn, req.shape, req.dtype,
+                               self.lanes, cond=req.cond,
+                               guidance_scale=req.guidance_scale,
+                               stream=self.stream, model_key=self.model_key)
+            arrays = fns.adapter.arrays(plan)
+            carry = fresh_carry(plan, self.lanes, req.shape, req.dtype,
+                                cond=req.cond, model_fn=self.model_fn,
+                                guard_every=self.guard_interval)
+            if not fns.warmed:
+                fns.warm(arrays, carry, cond=req.cond)
+                self._stats["warmups"] += 1
+            scale = spec.resolve_schedule().prior_scale(float(plan.ts[0]))
+            M = fns.adapter.n_steps_of(arrays)
+            batch = RunningBatch(key, plan, fns, arrays, carry, self.lanes,
+                                 scale, M)
+            self._batches.append(batch)
+            return batch
 
     def _derive_fn(self, batch: RunningBatch, req: Request) -> Callable:
         """Jitted rid -> (x_T, per-step keys) for one batch geometry.
@@ -357,21 +369,25 @@ class ContinuousBatcher:
         return fn
 
     def _join(self, batch: RunningBatch, lane: int, req: Request) -> None:
-        spec = batch.key[0]
-        x_T, keys = self._derive_fn(batch, req)(np.int32(req.rid),
-                                                np.int32(req.attempt))
-        min_i = req.min_steps
-        if min_i is None:
-            min_i = max(int(spec.predictor_order),
-                        int(spec.corrector_order))
-        batch.carry = batch.fns.join(
-            batch.arrays, batch.carry, lane, x_T, keys,
-            float(req.early_exit_tol), int(min_i),
-            float(req.guidance_scale), guard=self.guard_interval,
-            cond=req.cond)
-        batch.requests[lane] = req
-        batch.previews[lane] = []
-        self._stats["joins"] += 1
+        # arguments are built only while a profiler session records them
+        with (span("serve.join", rid=req.rid,
+                   queued_s=time.monotonic() - req.enqueued)
+              if span.is_enabled() else span("serve.join")):
+            spec = batch.key[0]
+            x_T, keys = self._derive_fn(batch, req)(np.int32(req.rid),
+                                                    np.int32(req.attempt))
+            min_i = req.min_steps
+            if min_i is None:
+                min_i = max(int(spec.predictor_order),
+                            int(spec.corrector_order))
+            batch.carry = batch.fns.join(
+                batch.arrays, batch.carry, lane, x_T, keys,
+                float(req.early_exit_tol), int(min_i),
+                float(req.guidance_scale), guard=self.guard_interval,
+                cond=req.cond)
+            batch.requests[lane] = req
+            batch.previews[lane] = []
+            self._stats["joins"] += 1
 
     def _admit(self) -> list:
         """Priority-ordered admission: shed expired, hold quarantined /
@@ -434,50 +450,52 @@ class ContinuousBatcher:
         # one host round-trip per tick: the flags and step indices come
         # back together (each device_get is a sync barrier on the tick);
         # the numerical-guard trips ride the same fetch
-        flags = jax.device_get(
-            {k: aux[k] for k in ("finished", "stepped", "failed", "i")})
-        fin, stepped, bad = (flags["finished"], flags["stepped"],
-                             flags["failed"])
-        if self.stream:
+        with span("serve.sync"):
+            flags = jax.device_get(
+                {k: aux[k] for k in ("finished", "stepped", "failed", "i")})
+        with span("serve.harvest"):
+            fin, stepped, bad = (flags["finished"], flags["stepped"],
+                                 flags["failed"])
+            if self.stream:
+                for lane, req in enumerate(batch.requests):
+                    if req is not None and stepped[lane]:
+                        batch.previews[lane].append(aux["x0"][lane])
+            if not fin.any() and not bad.any():
+                return []
+            steps = flags["i"]
+            label = bucket_label(batch.key)
+            results = []
             for lane, req in enumerate(batch.requests):
-                if req is not None and stepped[lane]:
-                    batch.previews[lane].append(aux["x0"][lane])
-        if not fin.any() and not bad.any():
-            return []
-        steps = flags["i"]
-        label = bucket_label(batch.key)
-        results = []
-        for lane, req in enumerate(batch.requests):
-            if req is None:
-                continue
-            if bad[lane]:
-                # in-graph guard tripped: the lane was already masked
-                # out; free it and retry/fail the request
-                self._note_failure(label)
-                results.extend(self._fail(
-                    req, ArithmeticError(
-                        f"non-finite state at step {int(steps[lane])}"),
-                    numerics=True))
+                if req is None:
+                    continue
+                if bad[lane]:
+                    # in-graph guard tripped: the lane was already masked
+                    # out; free it and retry/fail the request
+                    self._note_failure(label)
+                    results.extend(self._fail(
+                        req, ArithmeticError(
+                            f"non-finite state at step {int(steps[lane])}"),
+                        numerics=True))
+                    batch.requests[lane] = None
+                    batch.previews[lane] = []
+                    continue
+                if not fin[lane]:
+                    continue
+                previews = None
+                if self.stream:
+                    previews = jnp.stack(batch.previews[lane])
+                if req.degraded_to is not None:
+                    self._stats["degraded"] += 1
+                results.append(self._emit(self._make_result(
+                    rid=req.rid, x0=batch.carry["x_final"][lane],
+                    previews=previews, status="ok",
+                    n_steps=int(steps[lane]), attempts=req.attempt + 1,
+                    degraded_to=req.degraded_to)))
                 batch.requests[lane] = None
                 batch.previews[lane] = []
-                continue
-            if not fin[lane]:
-                continue
-            previews = None
-            if self.stream:
-                previews = jnp.stack(batch.previews[lane])
-            if req.degraded_to is not None:
-                self._stats["degraded"] += 1
-            results.append(self._emit(self._make_result(
-                rid=req.rid, x0=batch.carry["x_final"][lane],
-                previews=previews, status="ok",
-                n_steps=int(steps[lane]), attempts=req.attempt + 1,
-                degraded_to=req.degraded_to)))
-            batch.requests[lane] = None
-            batch.previews[lane] = []
-            self._stats["completed"] += 1
-            self._note_success(label)
-        return results
+                self._stats["completed"] += 1
+                self._note_success(label)
+            return results
 
     def _merge(self) -> None:
         """Fold same-key half-empty batches together (migrating each
@@ -539,40 +557,45 @@ class ContinuousBatcher:
         results completed this tick (possibly empty).
         """
         t0 = time.perf_counter()
-        results = self._admit()
-        if not self._batches:
-            self._stats["serve_s"] += time.perf_counter() - t0
+        with span("serve.tick"):
+            with span("serve.admit"):
+                results = self._admit()
+            if not self._batches:
+                self._stats["serve_s"] += time.perf_counter() - t0
+                return results
+            self._rr %= len(self._batches)
+            batch = self._batches[self._rr]
+            self._rr += 1
+            n_active = batch.n_active
+            tick_no = self._stats["ticks"]
+            try:
+                if self._inject is not None:
+                    self._inject.on_tick(tick_no, batch)
+                with span("serve.dispatch"):
+                    batch.carry, aux = batch.fns.step(batch.arrays,
+                                                      batch.carry)
+                self._stats["ticks"] += 1
+                evals = batch.fns.adapter.evals_per_tick * n_active
+                self._stats["model_evals"] += evals
+                self._stats["network_evals"] += evals * self._network_factor
+                bs = self._bucket_stats(batch.key)
+                bs["ticks"] += 1
+                bs["lane_steps"] += batch.lanes
+                bs["active_lane_steps"] += n_active
+                bs["wasted_lane_steps"] += batch.lanes - n_active
+                results.extend(self._harvest(batch, aux))
+            except Exception as err:
+                results.extend(self._contain(batch, err))
+            if results or self._pending:
+                with span("serve.merge"):
+                    self._merge()
+            dt = time.perf_counter() - t0
+            self._stats["serve_s"] += dt
+            # watchdog: injected latency, a straggling device, or a slow
+            # host all show up as a per-tick wall-time outlier
+            if self.watchdog.observe(tick_no, dt) and self.shed_on_straggler:
+                self._shed_deadlines = True
             return results
-        self._rr %= len(self._batches)
-        batch = self._batches[self._rr]
-        self._rr += 1
-        n_active = batch.n_active
-        tick_no = self._stats["ticks"]
-        try:
-            if self._inject is not None:
-                self._inject.on_tick(tick_no, batch)
-            batch.carry, aux = batch.fns.step(batch.arrays, batch.carry)
-            self._stats["ticks"] += 1
-            evals = batch.fns.adapter.evals_per_tick * n_active
-            self._stats["model_evals"] += evals
-            self._stats["network_evals"] += evals * self._network_factor
-            bs = self._bucket_stats(batch.key)
-            bs["ticks"] += 1
-            bs["lane_steps"] += batch.lanes
-            bs["active_lane_steps"] += n_active
-            bs["wasted_lane_steps"] += batch.lanes - n_active
-            results.extend(self._harvest(batch, aux))
-        except Exception as err:
-            results.extend(self._contain(batch, err))
-        if results or self._pending:
-            self._merge()
-        dt = time.perf_counter() - t0
-        self._stats["serve_s"] += dt
-        # watchdog: injected latency, a straggling device, or a slow
-        # host all show up as a per-tick wall-time outlier
-        if self.watchdog.observe(tick_no, dt) and self.shed_on_straggler:
-            self._shed_deadlines = True
-        return results
 
     def _next_wake(self) -> float:
         """Earliest monotonic time any held pending request becomes

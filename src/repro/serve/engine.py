@@ -40,6 +40,11 @@ guidance every guided evaluation is one fused network forward over a
 (warmup compiles exactly that doubled-lane graph, and a padded slot
 wastes two network lanes instead of one). Padded lanes are reported
 separately as ``padded_slots`` (they cost compute but serve nobody).
+
+Each microbatch is a ``serve.microbatch`` profiler span (as in
+:mod:`repro.serve.continuous`) with children ``serve.warm`` (only when
+it compiles), ``serve.prepare`` (keys and the initial noise),
+``serve.dispatch``, ``serve.sync`` and ``serve.harvest``.
 """
 
 from __future__ import annotations
@@ -51,6 +56,7 @@ from typing import Any, Callable, Hashable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from ..core.denoiser import Denoiser
 from ..core.samplers import (SamplerSpec, build_plan, compile_cache_stats,
@@ -425,7 +431,8 @@ class ServeEngine:
         failure, runtime error at the sync barrier) fails ONLY this
         bucket's requests — queue and other buckets are untouched."""
         try:
-            return self._serve(mb)
+            with span("serve.microbatch"):
+                return self._serve(mb)
         except Exception as err:
             self._note_failure(bucket_label(mb.key))
             results = []
@@ -445,12 +452,13 @@ class ServeEngine:
         ident = (mb.key, mb.size)
         if ident in self._warmed:
             return
-        plan = build_plan(mb.spec)
-        warmup(plan, self.model_fn, mb.shape, jnp.dtype(mb.dtype),
-               batch=mb.size, mesh=self.mesh, data_axis=self.data_axis,
-               cfg_axis=self.cfg_axis,
-               cond=mb.requests[0].cond, trajectory=self.stream,
-               model_key=self.model_key, donate=self.donate)
+        with span("serve.warm"):
+            plan = build_plan(mb.spec)
+            warmup(plan, self.model_fn, mb.shape, jnp.dtype(mb.dtype),
+                   batch=mb.size, mesh=self.mesh, data_axis=self.data_axis,
+                   cfg_axis=self.cfg_axis,
+                   cond=mb.requests[0].cond, trajectory=self.stream,
+                   model_key=self.model_key, donate=self.donate)
         self._warmed.add(ident)
         self._stats["warmups"] += 1
 
@@ -504,93 +512,96 @@ class ServeEngine:
 
     def _serve(self, mb: MicroBatch) -> list[ServeResult]:
         self.warmup_bucket(mb)
-        spec, shape = mb.spec, mb.shape
-        dtype = jnp.dtype(mb.dtype)
-        plan = build_plan(spec)
-        rids = mb.rids()
+        with span("serve.prepare"):
+            spec, shape = mb.spec, mb.shape
+            dtype = jnp.dtype(mb.dtype)
+            plan = build_plan(spec)
+            rids = mb.rids()
 
-        t0 = time.perf_counter()
-        noise_keys = fold_keys(self._noise_base, rids)
-        solve_keys = fold_keys(self._solve_base, rids)
-        attempts = [r.attempt for r in mb.requests] + [0] * mb.n_padded
-        if any(attempts):  # retries draw fresh per-attempt subkeys;
-            noise_keys = retry_fold(noise_keys, attempts)  # attempt 0
-            solve_keys = retry_fold(solve_keys, attempts)  # is bitwise
-        scale = spec.resolve_schedule().prior_scale(float(plan.ts[0]))
-        x_T = jax.vmap(
-            lambda k: scale * jax.random.normal(k, shape, dtype)
-        )(noise_keys)
-        if self._inject is not None:
-            x_T = self._inject.on_solve(self._stats["microbatches"],
-                                        mb, x_T)
-        cond_b = mb.stacked_cond()
-        g_scales = mb.scales()
+            t0 = time.perf_counter()
+            noise_keys = fold_keys(self._noise_base, rids)
+            solve_keys = fold_keys(self._solve_base, rids)
+            attempts = [r.attempt for r in mb.requests] + [0] * mb.n_padded
+            if any(attempts):  # retries draw fresh per-attempt subkeys;
+                noise_keys = retry_fold(noise_keys, attempts)  # attempt 0
+                solve_keys = retry_fold(solve_keys, attempts)  # is bitwise
+            scale = spec.resolve_schedule().prior_scale(float(plan.ts[0]))
+            x_T = jax.vmap(
+                lambda k: scale * jax.random.normal(k, shape, dtype)
+            )(noise_keys)
+            if self._inject is not None:
+                x_T = self._inject.on_solve(self._stats["microbatches"],
+                                            mb, x_T)
+            cond_b = mb.stacked_cond()
+            g_scales = mb.scales()
+        with span("serve.dispatch"):
+            if self.mesh is not None:
+                out = sample_sharded(
+                    plan, self.model_fn, x_T, solve_keys, mesh=self.mesh,
+                    data_axis=self.data_axis, cfg_axis=self.cfg_axis,
+                    cond=cond_b,
+                    guidance_scale=g_scales, trajectory=self.stream,
+                    model_key=self.model_key, donate=self.donate)
+            else:
+                out = sample_batched(
+                    plan, self.model_fn, x_T, solve_keys, cond=cond_b,
+                    guidance_scale=g_scales,
+                    trajectory=self.stream, model_key=self.model_key)
+        with span("serve.sync"):
+            if self.stream:
+                x0, traj = out
+                previews = jax.block_until_ready(traj["x0"])
+            else:
+                x0, previews = out, None
+            x0 = jax.block_until_ready(x0)
+        with span("serve.harvest"):
+            dt = time.perf_counter() - t0
+            self._stats["serve_s"] += dt
+            self.watchdog.observe(self._stats["microbatches"], dt)
 
-        if self.mesh is not None:
-            out = sample_sharded(
-                plan, self.model_fn, x_T, solve_keys, mesh=self.mesh,
-                data_axis=self.data_axis, cfg_axis=self.cfg_axis,
-                cond=cond_b,
-                guidance_scale=g_scales, trajectory=self.stream,
-                model_key=self.model_key, donate=self.donate)
-        else:
-            out = sample_batched(
-                plan, self.model_fn, x_T, solve_keys, cond=cond_b,
-                guidance_scale=g_scales,
-                trajectory=self.stream, model_key=self.model_key)
-        if self.stream:
-            x0, traj = out
-            previews = jax.block_until_ready(traj["x0"])
-        else:
-            x0, previews = out, None
-        x0 = jax.block_until_ready(x0)
-        dt = time.perf_counter() - t0
-        self._stats["serve_s"] += dt
-        self.watchdog.observe(self._stats["microbatches"], dt)
+            n_real = len(mb.requests)
+            self._stats["requests"] += n_real
+            self._stats["microbatches"] += 1
+            self._stats["padded_slots"] += mb.n_padded
+            self._stats["model_evals"] += spec.nfe * n_real
+            self._stats["network_evals"] += spec.network_nfe * n_real
+            # per-bucket lane-step accounting, same shape of numbers as the
+            # step scheduler: here every lane rides the full solve, so a
+            # padded lane wastes n_steps lane-steps in one indivisible chunk
+            label = bucket_label(mb.key)
+            bs = self._buckets.setdefault(label, {
+                "ticks": 0, "lane_steps": 0, "active_lane_steps": 0,
+                "wasted_lane_steps": 0})
+            bs["ticks"] += spec.n_steps
+            bs["lane_steps"] += mb.size * spec.n_steps
+            bs["active_lane_steps"] += n_real * spec.n_steps
+            bs["wasted_lane_steps"] += mb.n_padded * spec.n_steps
 
-        n_real = len(mb.requests)
-        self._stats["requests"] += n_real
-        self._stats["microbatches"] += 1
-        self._stats["padded_slots"] += mb.n_padded
-        self._stats["model_evals"] += spec.nfe * n_real
-        self._stats["network_evals"] += spec.network_nfe * n_real
-        # per-bucket lane-step accounting, same shape of numbers as the
-        # step scheduler: here every lane rides the full solve, so a
-        # padded lane wastes n_steps lane-steps in one indivisible chunk
-        label = bucket_label(mb.key)
-        bs = self._buckets.setdefault(label, {
-            "ticks": 0, "lane_steps": 0, "active_lane_steps": 0,
-            "wasted_lane_steps": 0})
-        bs["ticks"] += spec.n_steps
-        bs["lane_steps"] += mb.size * spec.n_steps
-        bs["active_lane_steps"] += n_real * spec.n_steps
-        bs["wasted_lane_steps"] += mb.n_padded * spec.n_steps
+            # post-solve numerical guard (the solve scheduler has no
+            # in-graph per-step check — the whole solve is one dispatch —
+            # so any non-zero guard_interval means "check the final latent")
+            bad = np.zeros(n_real, bool)
+            if self.guard_interval and n_real:
+                flat = np.asarray(x0[:n_real], np.float32).reshape(n_real, -1)
+                bad = ~np.isfinite(flat).all(axis=1)
 
-        # post-solve numerical guard (the solve scheduler has no
-        # in-graph per-step check — the whole solve is one dispatch —
-        # so any non-zero guard_interval means "check the final latent")
-        bad = np.zeros(n_real, bool)
-        if self.guard_interval and n_real:
-            flat = np.asarray(x0[:n_real], np.float32).reshape(n_real, -1)
-            bad = ~np.isfinite(flat).all(axis=1)
-
-        results = []
-        for lane, req in enumerate(mb.requests):  # pad lanes dropped here
-            if bad[lane]:
-                self._note_failure(label)
-                results.extend(self._fail(
-                    req, ArithmeticError("non-finite final latent"),
-                    numerics=True))
-                continue
-            if req.degraded_to is not None:
-                self._stats["degraded"] += 1
-            results.append(self._emit(ServeResult(
-                rid=req.rid, x0=x0[lane],
-                previews=previews[lane] if previews is not None else None,
-                attempts=req.attempt + 1, degraded_to=req.degraded_to)))
-            self._stats["completed"] += 1
-            self._note_success(label)
-        return results
+            results = []
+            for lane, req in enumerate(mb.requests):  # pad lanes dropped here
+                if bad[lane]:
+                    self._note_failure(label)
+                    results.extend(self._fail(
+                        req, ArithmeticError("non-finite final latent"),
+                        numerics=True))
+                    continue
+                if req.degraded_to is not None:
+                    self._stats["degraded"] += 1
+                results.append(self._emit(ServeResult(
+                    rid=req.rid, x0=x0[lane],
+                    previews=previews[lane] if previews is not None else None,
+                    attempts=req.attempt + 1, degraded_to=req.degraded_to)))
+                self._stats["completed"] += 1
+                self._note_success(label)
+            return results
 
     # -------------------------------------------------------------- stats
     def stats(self) -> dict:
