@@ -15,13 +15,11 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .flash_attention import flash_attention as _flash_kernel
 from .rwkv6_scan import rwkv6_wkv as _wkv_kernel
 from .sa_fused import sa_fused_update as _sa_fused_kernel
 from .sa_update import sa_update as _sa_kernel
 
-__all__ = ["sa_update", "sa_fused_update", "flash_attention", "wkv",
-           "on_tpu"]
+__all__ = ["sa_update", "sa_fused_update", "wkv", "on_tpu"]
 
 
 def on_tpu() -> bool:
@@ -44,13 +42,6 @@ def sa_fused_update(x, buf, xi, coeffs, *, mode: str = "auto"):
     if mode == "jnp" or (mode == "auto" and not on_tpu()):
         return ref.sa_fused_update_ref(x, buf, xi, coeffs)
     return _sa_fused_kernel(x, buf, xi, coeffs)
-
-
-def flash_attention(q, k, v, *, causal: bool = True, mode: str = "auto",
-                    bq: int = 512, bk: int = 512):
-    if mode == "jnp" or (mode == "auto" and not on_tpu()):
-        return ref.flash_attention_ref(q, k, v, causal=causal)
-    return _flash_kernel(q, k, v, causal=causal, bq=bq, bk=bk)
 
 
 def wkv(r, k, v, logw, u, S0, *, chunk: int = 64, mode: str = "auto"):

@@ -11,12 +11,16 @@ compressed space — the production optimization; see EXPERIMENTS.md §Perf).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Primitive
+from jax.interpreters import batching, mlir
 
+from ..kernels.flash_attention import flash_attention
 from .common import (ParamDef, apply_mrope, apply_rope, rms_norm,
                      shard_heads_dim)
 
@@ -44,10 +48,6 @@ class AttentionConfig:
     causal: bool = True
     mla: MLAConfig | None = None
     attn_logit_softcap: float | None = None
-    #: route the no-cache path (causal LM prefill or bidirectional
-    #: denoiser blocks) through kernels/flash_attention (jnp oracle on
-    #: CPU, Mosaic kernel on TPU)
-    use_flash: bool = False
 
 
 # ---------------------------------------------------------------------------
@@ -97,33 +97,153 @@ def cache_shape(cfg: AttentionConfig, batch: int, s_max: int, dtype=jnp.bfloat16
 # ---------------------------------------------------------------------------
 
 
+#: shortest sequence at which the blocked kernel beats the jnp path on a
+#: TPU v5e: it wins at 512 and 1024 tokens and loses at 256 (DiT-XL/2
+#: layers, PERF.md)
+KERNEL_MIN_LEN = 512
+
+
+def use_kernel(backend: str, q_len: int, kv_len: int, cached: bool,
+               softcap, causal: bool) -> bool:
+    """Whether attention over ``q_len`` queries and ``kv_len`` keys runs
+    as the blocked Pallas kernel: on TPU, without a KV cache or a logit
+    softcap, at lengths where the chip shows it winning. The length
+    threshold and the kernel's block sizes were measured on a TPU v5e at
+    DiT-XL/2's shapes only (non-causal, 16 heads of 72), so causal (LM)
+    attention keeps the jnp path until a cell measures it. Whether the
+    program spans one device is known only when it is lowered
+    (``_kernel_p``)."""
+    return (backend == "tpu" and not cached and softcap is None
+            and not causal and min(q_len, kv_len) >= KERNEL_MIN_LEN)
+
+
 def _sdpa(q, k, v, *, causal: bool, q_offset=0, kv_len=None, softcap=None,
-          q_chunk: int = 256):
-    """q [B,S,H,hd]; k,v [B,T,K,hd]. Dispatcher: q-chunked via lax.map for
-    long sequences (bounds live attention scores to [B,H,q_chunk,T] —
-    the jnp stand-in for the flash kernel's blocking; XLA frees each chunk
-    before the next because lax.map is sequential), direct otherwise.
-    The scores, softmax and weighted values run under
-    ``named_scope("attention")``, which names their ops in a profile."""
+          q_chunk: int = 256, wo=None):
+    """q [B,S,H,hd]; k,v [B,T,K,hd] -> [B,S,H,hd]. Dispatcher: the blocked
+    Pallas kernel where ``use_kernel`` says so (``_kernel_attention``),
+    else ``_attention_jnp``. Either runs under ``named_scope("attention")``,
+    which names its ops in a profile.
+
+    With ``wo`` [H,hd,d] it returns the output projection [B,S,d], outside
+    the scope, in the form that suits the path taken: one [H*hd, d]
+    matmul of the kernel's merged-head output (a per-head product would
+    relayout it), and the per-head einsum on the jnp path (a merged one
+    made XLA relayout the values product: step cell p50 +4-6%, PERF.md)."""
+    kernel = use_kernel(jax.default_backend(), q.shape[1], k.shape[1],
+                        kv_len is not None, softcap, causal)
     with jax.named_scope("attention"):
-        B, S, H, hd = q.shape
-        if S > q_chunk and S % q_chunk == 0:
-            n = S // q_chunk
-            qc = jnp.swapaxes(q.reshape(B, n, q_chunk, H, hd), 0, 1)
-            offs = q_offset + jnp.arange(n) * q_chunk
+        if kernel:
+            out = _kernel_attention(q, k, v, causal)
+        else:
+            out = _attention_jnp(q, k, v, causal=causal, q_offset=q_offset,
+                                 kv_len=kv_len, softcap=softcap,
+                                 q_chunk=q_chunk)
+    if wo is None:
+        return out
+    if kernel:
+        return jnp.einsum("bsn,nd->bsd", out.reshape(*out.shape[:2], -1),
+                          wo.reshape(-1, wo.shape[-1]))
+    return jnp.einsum("bshk,hkd->bsd", out, wo)
 
-            @jax.checkpoint
-            def one(args):
-                # checkpointed: map-backward saves only the chunk inputs, not
-                # the [B,H,chunk,T] softmax residuals of every chunk at once
-                qi, off = args
-                return _sdpa_block(qi, k, v, causal=causal, q_offset=off,
-                                   kv_len=kv_len, softcap=softcap)
 
-            out = jax.lax.map(one, (qc, offs))
-            return jnp.swapaxes(out, 0, 1).reshape(B, S, H, v.shape[-1])
-        return _sdpa_block(q, k, v, causal=causal, q_offset=q_offset,
-                           kv_len=kv_len, softcap=softcap)
+def _attention_jnp(q, k, v, *, causal: bool, q_offset=0, kv_len=None,
+                   softcap=None, q_chunk: int = 256):
+    """q-chunked via lax.map for long sequences (bounds live attention
+    scores to [B,H,q_chunk,T]; XLA frees each chunk before the next
+    because lax.map is sequential), direct otherwise."""
+    B, S, H, hd = q.shape
+    if S > q_chunk and S % q_chunk == 0:
+        n = S // q_chunk
+        qc = jnp.swapaxes(q.reshape(B, n, q_chunk, H, hd), 0, 1)
+        offs = q_offset + jnp.arange(n) * q_chunk
+
+        @jax.checkpoint
+        def one(args):
+            # checkpointed: map-backward saves only the chunk inputs, not
+            # the [B,H,chunk,T] softmax residuals of every chunk at once
+            qi, off = args
+            return _sdpa_block(qi, k, v, causal=causal, q_offset=off,
+                               kv_len=kv_len, softcap=softcap)
+
+        out = jax.lax.map(one, (qc, offs))
+        return jnp.swapaxes(out, 0, 1).reshape(B, S, H, v.shape[-1])
+    return _sdpa_block(q, k, v, causal=causal, q_offset=q_offset,
+                       kv_len=kv_len, softcap=softcap)
+
+
+def _kernel_call(q, k, v, *, causal: bool):
+    """The Pallas kernel in the model's layout, with bfloat16 operands
+    (what the MXU takes for every default-precision dot of the backbone)
+    and float32 softmax."""
+    return flash_attention(q, k, v, causal=causal,
+                           dtype=jnp.bfloat16).astype(q.dtype)
+
+
+def _one_device(axis_context) -> bool:
+    """Whether a program lowered in ``axis_context`` runs each instance on
+    one device: XLA cannot partition a Pallas call, so it may appear only
+    in a one-device program or where every mesh axis is manual (inside
+    ``shard_map``)."""
+    mesh = getattr(axis_context, "mesh", None)
+    if mesh is not None:
+        manual = set(axis_context.manual_axes) | set(
+            getattr(mesh, "manual_axes", ()))
+        return manual >= set(mesh.axis_names)
+    return getattr(axis_context, "num_devices", 1) == 1
+
+
+def _lower_kernel_p(ctx, q, k, v, *, causal):
+    # decided here, where the program's devices are known: the kernel in
+    # a one-device program, the jnp path where GSPMD partitions it
+    # (serving's request or CFG axis over a mesh, training on a slice)
+    fn = _kernel_call if _one_device(ctx.module_context.axis_context) \
+        else _attention_jnp
+    fn = _over_lead(functools.partial(fn, causal=causal), ctx.avals_in[0].ndim)
+    return mlir.lower_fun(fn, multiple_results=False)(ctx, q, k, v)
+
+
+def _over_lead(fn, ndim):
+    """``fn`` of [B,S,H,hd] arrays, vmapped over the leading axes that
+    batching put before B."""
+    for _ in range(ndim - 4):
+        fn = jax.vmap(fn)
+    return fn
+
+
+def _batch_kernel_p(args, dims, *, causal):
+    # a vmapped axis (the serving lanes, the CFG pair) leads; the lowering
+    # vmaps the kernel over it, so no reshape meets the projections' layout
+    n = next(a.shape[d] for a, d in zip(args, dims)
+             if d is not batching.not_mapped)
+    args = [jnp.broadcast_to(a, (n,) + a.shape) if d is batching.not_mapped
+            else jnp.moveaxis(a, d, 0) for a, d in zip(args, dims)]
+    return _kernel_p.bind(*args, causal=causal), 0
+
+
+#: the kernel as one primitive, so that the one-device question is
+#: answered at lowering
+_kernel_p = Primitive("attention_kernel")
+_kernel_p.def_impl(
+    lambda q, k, v, *, causal: _over_lead(
+        functools.partial(_kernel_call, causal=causal), q.ndim)(q, k, v))
+_kernel_p.def_abstract_eval(lambda q, k, v, *, causal: q)
+mlir.register_lowering(_kernel_p, _lower_kernel_p)
+batching.primitive_batchers[_kernel_p] = _batch_kernel_p
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(3,))
+def _kernel_attention(q, k, v, causal):
+    """q [B,S,H,hd]; k,v [B,T,K,hd] -> [B,S,H,hd] through the kernel.
+    The kernel has no derivative of its own: tangents, and by transposing
+    them gradients, are the jnp path's at the same inputs."""
+    return _kernel_p.bind(q, k, v, causal=causal)
+
+
+@_kernel_attention.defjvp
+def _kernel_attention_jvp(causal, primals, tangents):
+    _, out_dot = jax.jvp(functools.partial(_attention_jnp, causal=causal),
+                         primals, tangents)
+    return _kernel_attention(*primals, causal), out_dot
 
 
 def _sdpa_block(q, k, v, *, causal: bool, q_offset=0, kv_len=None, softcap=None):
@@ -205,15 +325,8 @@ def gqa_forward(
     v = shard_heads_dim(v)
 
     if cache is None:
-        if cfg.use_flash and cfg.attn_logit_softcap is None:
-            from ..kernels import ops as kops
-            o = kops.flash_attention(
-                jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-                jnp.swapaxes(v, 1, 2), causal=causal,
-            )
-            out = jnp.swapaxes(o, 1, 2)
-        else:
-            out = _sdpa(q, k, v, causal=causal, softcap=cfg.attn_logit_softcap)
+        y = _sdpa(q, k, v, causal=causal, softcap=cfg.attn_logit_softcap,
+                  wo=p["wo"])
     else:
         ck = jax.lax.dynamic_update_slice(
             cache["k"], k.astype(cache["k"].dtype), (0, cache_index, 0, 0)
@@ -222,11 +335,11 @@ def gqa_forward(
             cache["v"], v.astype(cache["v"].dtype), (0, cache_index, 0, 0)
         )
         cache = {"k": ck, "v": cv}
-        out = _sdpa(
+        y = _sdpa(
             q, ck, cv, causal=causal, q_offset=cache_index,
             kv_len=cache_index + S, softcap=cfg.attn_logit_softcap,
+            wo=p["wo"],
         )
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, cache
 
 

@@ -13,6 +13,13 @@ from repro.kernels.sa_fused import sa_fused_update
 from repro.kernels.sa_update import LANE_ALIGN, choose_tile, sa_update
 
 
+def attention_ref(q, k, v, *, causal):
+    """``flash_attention_ref`` ([B,H,S,hd]) in the kernel's layout
+    ([B,S,H,hd])."""
+    t = lambda a: jnp.swapaxes(a, 1, 2)
+    return t(flash_attention_ref(t(q), t(k), t(v), causal=causal))
+
+
 @pytest.mark.parametrize("shape", [(64,), (4, 100, 7), (2, 33, 5, 3), (1,)])
 @pytest.mark.parametrize("P", [1, 3, 5])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -149,11 +156,11 @@ def test_sa_update_unaligned_sizes_are_exact():
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_sweep(B, H, K, S, hd, bq, bk, dtype):
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    q = jax.random.normal(ks[0], (B, H, S, hd), dtype)
-    k = jax.random.normal(ks[1], (B, K, S, hd), dtype)
-    v = jax.random.normal(ks[2], (B, K, S, hd), dtype)
+    q = jax.random.normal(ks[0], (B, S, H, hd), dtype)
+    k = jax.random.normal(ks[1], (B, S, K, hd), dtype)
+    v = jax.random.normal(ks[2], (B, S, K, hd), dtype)
     out = flash_attention(q, k, v, causal=True, bq=bq, bk=bk)
-    ref = flash_attention_ref(q, k, v, causal=True)
+    ref = attention_ref(q, k, v, causal=True)
     tol = 2e-5 if dtype == jnp.float32 else 4e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol, rtol=tol)
@@ -167,11 +174,11 @@ def test_flash_attention_ragged_lengths(S, causal, dtype):
     block multiples (masked final q/k blocks) must match the reference at
     f32 and bf16. Small shapes so the sweep stays in the fast suite."""
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
-    q = jax.random.normal(ks[0], (1, 2, S, 16), dtype)
-    k = jax.random.normal(ks[1], (1, 2, S, 16), dtype)
-    v = jax.random.normal(ks[2], (1, 2, S, 16), dtype)
+    q = jax.random.normal(ks[0], (1, S, 2, 16), dtype)
+    k = jax.random.normal(ks[1], (1, S, 2, 16), dtype)
+    v = jax.random.normal(ks[2], (1, S, 2, 16), dtype)
     out = flash_attention(q, k, v, causal=causal, bq=16, bk=16)
-    ref = flash_attention_ref(q, k, v, causal=causal)
+    ref = attention_ref(q, k, v, causal=causal)
     tol = 2e-5 if dtype == jnp.float32 else 4e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol, rtol=tol)
@@ -179,12 +186,45 @@ def test_flash_attention_ragged_lengths(S, causal, dtype):
 
 def test_flash_attention_noncausal():
     ks = jax.random.split(jax.random.PRNGKey(2), 3)
-    q = jax.random.normal(ks[0], (1, 2, 64, 32))
-    k = jax.random.normal(ks[1], (1, 2, 64, 32))
-    v = jax.random.normal(ks[2], (1, 2, 64, 32))
+    q = jax.random.normal(ks[0], (1, 64, 2, 32))
+    k = jax.random.normal(ks[1], (1, 64, 2, 32))
+    v = jax.random.normal(ks[2], (1, 64, 2, 32))
     out = flash_attention(q, k, v, causal=False, bq=32, bk=32)
-    ref = flash_attention_ref(q, k, v, causal=False)
+    ref = attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("S,bq,bk", [
+    (256, None, None),    # the shapes' own blocks: bk == T
+    (256, 128, 128),      # bk < T: online softmax over two KV blocks
+    (1024, None, None),   # bk == T
+    (1024, 256, 512),     # bk < T
+], ids=["256-one-kv-block", "256-two-kv-blocks", "1024-one-kv-block",
+        "1024-two-kv-blocks"])
+def test_flash_attention_dit_head_dim_bf16(S, bq, bk):
+    """The denoiser's case: non-causal, head_dim 72, bfloat16 operands
+    (f32 accumulation and softmax), at the DiT-XL/2 token counts."""
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    q, k, v = (jax.random.normal(kk, (1, S, 4, 72), jnp.bfloat16)
+               for kk in ks)
+    out = flash_attention(q, k, v, causal=False, bq=bq, bk=bk)
+    assert out.dtype == jnp.bfloat16
+    ref = attention_ref(q, k, v, causal=False)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=4e-2, rtol=4e-2)
+
+
+def test_flash_attention_casts_operands_after_scaling():
+    """``dtype`` sets the dots' operand type: float32 q/k/v in, bfloat16
+    operands and output, within bfloat16 rounding of the f32 oracle."""
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    q, k, v = (jax.random.normal(kk, (2, 256, 2, 72)) for kk in ks)
+    out = flash_attention(q, k, v, causal=False, dtype=jnp.bfloat16)
+    assert out.dtype == jnp.bfloat16
+    ref = attention_ref(q, k, v, causal=False)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               atol=4e-2, rtol=4e-2)
 
 
 @pytest.mark.slow

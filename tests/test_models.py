@@ -194,3 +194,70 @@ def test_param_counts_match_published():
     # deepseek active ~37B
     _, active = get_config("deepseek-v3-671b").param_count()
     assert abs(active - 37e9) / 37e9 < 0.1
+
+
+@pytest.mark.parametrize("backend,q_len,kv_len,cached,softcap,causal,want", [
+    ("cpu", 1024, 1024, False, None, False, False),  # off the chip: jnp
+    ("tpu", 1024, 1024, True, None, False, False),   # KV cache (LM decode)
+    ("tpu", 1024, 1024, False, 50.0, False, False),  # logit softcap
+    ("tpu", 256, 256, False, None, False, False),    # DiT at 256 px: jnp wins
+    ("tpu", 1024, 1024, False, None, True, False),   # causal: not measured
+    ("tpu", 1024, 1024, False, None, False, True),   # DiT-XL/2 at 512 px
+], ids=["cpu", "cached", "softcap", "dit-256", "causal", "dit-1024"])
+def test_attention_kernel_dispatch(backend, q_len, kv_len, cached, softcap,
+                                   causal, want):
+    from repro.models.attention import use_kernel
+    assert use_kernel(backend, q_len, kv_len, cached, softcap,
+                      causal) is want
+
+
+def _qkv(shape=(2, 256, 4, 72)):
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    return tuple(jax.random.normal(kk, shape) for kk in ks)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_sdpa_kernel_path_matches_jnp_path(monkeypatch, causal):
+    """``_sdpa``'s kernel branch (layout changes, bf16 operands, the
+    kernel in interpret mode) against its jnp branch, [B,S,H,hd] in and
+    out, within bfloat16 rounding, eager and under jit and vmap; and with
+    the output projection, which each branch writes in its own form."""
+    from repro.models import attention
+    q, k, v = _qkv()
+    wo = jax.random.normal(jax.random.PRNGKey(6), (4, 72, 32)) / 17.0
+    want = attention._sdpa(q, k, v, causal=causal)
+    want_y = attention._sdpa(q, k, v, causal=causal, wo=wo)
+    monkeypatch.setattr(attention, "use_kernel", lambda *a: True)
+    sdpa = lambda q, k, v: attention._sdpa(q, k, v, causal=causal)
+    two = lambda a: jnp.stack([a, a], axis=1)  # two lanes, k shared
+    lanes = jax.jit(jax.vmap(sdpa, in_axes=(1, None, 1)))(two(q), k, two(v))
+    for got in (sdpa(q, k, v), jax.jit(sdpa)(q, k, v), lanes[0], lanes[1]):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=4e-2, rtol=4e-2)
+    got_y = attention._sdpa(q, k, v, causal=causal, wo=wo)
+    assert got_y.shape == want_y.shape == (2, 256, 32)
+    np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y),
+                               atol=4e-2, rtol=4e-2)
+
+
+@pytest.mark.parametrize("mode", ["grad", "jvp"])
+def test_sdpa_kernel_path_differentiates_as_jnp_path(monkeypatch, mode):
+    """The kernel branch has the jnp branch's derivatives (training takes
+    ``jax.grad`` through the DiT's attention, the tests ``jax.jvp``)."""
+    from repro.models import attention
+    q, k, v = _qkv((1, 64, 2, 16))
+    w = jax.random.normal(jax.random.PRNGKey(5), q.shape)
+    loss = lambda q, k, v: jnp.sum(w * attention._sdpa(q, k, v,
+                                                      causal=False))
+
+    def derivs():
+        if mode == "grad":
+            return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+        return jax.jvp(loss, (q, k, v), (w, -w, 2 * w))[1:]
+
+    want = derivs()
+    monkeypatch.setattr(attention, "use_kernel", lambda *a: True)
+    for g, h in zip(derivs(), want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(h),
+                                   atol=1e-5, rtol=1e-5)

@@ -7,12 +7,17 @@ chip and check that the compiled program holds the kernel
 (``tpu_custom_call``): the combine kernels at the DiT-XL/2 latent
 (256 x 16) alone and under the serving lanes' ``vmap`` (8 lanes,
 per-lane coefficients as in the step function), a latent whose size is
-not a multiple of 128, and non-causal flash attention at DiT-XL/2's
-head_dim 72.
+not a multiple of 128, non-causal flash attention at DiT-XL/2's
+head_dim 72 at 256 and 1024 tokens, and a DiT-XL/2-width denoiser layer
+whose attention the program dispatches to that kernel on one chip, and
+keeps on the jnp path with its lanes split over the four chips.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
+
+import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -87,7 +92,77 @@ def test_combine_kernel_compiles_with_shared_coefficients(one_chip):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 def test_flash_attention_compiles_at_dit_head_dim(one_chip, dtype):
-    q = jax.ShapeDtypeStruct((2, 16, 256, 72), dtype, sharding=one_chip)
     fn = lambda q, k, v: flash_attention(q, k, v, causal=False,
                                          interpret=False)
-    assert "tpu_custom_call" in compiled_text(fn, q, q, q)
+    for tokens in (256, 1024):  # DiT-XL/2 at 256 and 512 px
+        q = jax.ShapeDtypeStruct((2, tokens, 16, 72), dtype,
+                                 sharding=one_chip)
+        assert "tpu_custom_call" in compiled_text(fn, q, q, q)
+
+
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _in_attention(op_name):
+    return "attention" in re.split(r"[/();]", op_name)
+
+
+@pytest.mark.parametrize("tokens", [256, 1024])
+def test_dit_denoise_runs_attention_as_the_kernel(one_chip, monkeypatch,
+                                                  tokens):
+    """A 1-layer DiT-XL/2-width ``denoise`` (d 1152, 16 heads, head_dim
+    72), as the serving lanes call it (lanes x the CFG pair, vmapped),
+    compiled for the chip: where ``use_kernel`` picks the kernel, its
+    attention is the Pallas kernel under the ``attention`` name scope and
+    no jnp score product is left there; elsewhere it is the jnp path."""
+    from repro.models import attention
+    kernel = attention.use_kernel("tpu", tokens, tokens, False, None, False)
+    # the program asks the default backend, which is the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    calls, scores = _dit_layer_attention(one_chip, one_chip, tokens)
+    if kernel:
+        assert calls and all(_in_attention(op) for op in calls)
+        assert not scores
+    else:
+        assert not calls and scores
+
+
+def test_dit_denoise_on_a_mesh_keeps_jnp_attention(topo, monkeypatch):
+    """The same layer with the lanes split over the four chips of the
+    described v5e:2x2 (``--sharded`` serving, the request axis over
+    ``data``): XLA cannot partition a Pallas call, so the lowering keeps
+    the jnp path there, and the program compiles."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(topo.devices, ("data",))
+    calls, scores = _dit_layer_attention(
+        NamedSharding(mesh, PartitionSpec()),
+        NamedSharding(mesh, PartitionSpec("data")), 1024)
+    assert not calls and scores
+
+
+def _dit_layer_attention(replicated, lanes_sharding, tokens):
+    """Compile a 1-layer DiT-XL/2-width ``denoise`` for 8 lanes x the CFG
+    pair (vmapped, as the serving lanes call it); return the op names of
+    its Pallas calls and whether a jnp score product sits under the
+    ``attention`` scope."""
+    from repro.configs import dit_xl_2
+    from repro.models import build_model, init_params
+    model = build_model(dataclasses.replace(dit_xl_2.full(), n_layers=1))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=replicated),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0),
+                                           model.param_defs())))
+
+    def lanes(p, z, t):
+        one = lambda zz: model.denoise(p, zz[None], t)[0]
+        return jax.vmap(jax.vmap(one))(z)
+
+    z = jax.ShapeDtypeStruct((8, 2, tokens, dit_xl_2.LATENT_DIM),
+                             jnp.float32, sharding=lanes_sharding)
+    t = jax.ShapeDtypeStruct((), jnp.float32, sharding=replicated)
+    text = compiled_text(lanes, params, z, t)
+    calls = [_OP_NAME.search(ln).group(1) for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    att = [op for op in _OP_NAME.findall(text) if _in_attention(op)]
+    return calls, any("bskgd,btkd->bkgst" in op for op in att)
