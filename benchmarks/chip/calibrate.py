@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import argparse
 import copy
+import dataclasses
 import json
 import os
 import sys
@@ -68,9 +69,9 @@ def readings(cell, seeds, control_seeds, seconds, out=None,
 def sweep(cell, rates, seconds, seed, out=None) -> None:
     from .stats import quantile
     for rate in rates:
-        c = copy.deepcopy(cell)
+        c = dataclasses.replace(cell, traffic=copy.deepcopy(cell.traffic),
+                                limits=dict(cell.limits, sample=0))
         c.traffic["arrivals"]["rate_per_s"] = rate
-        c.limits = dict(c.limits, sample=0)
         _, measured = run.measure(c, seed, seconds, False)
         recs = measured.records
         lat = [r.done - r.due for r in recs if r.status == "ok"]

@@ -2,7 +2,8 @@
 
 These are the benchmark's own arithmetic: the program under test never
 supplies a count. A configuration file (``configs/*.json``) gives the
-sizes; a request gives the latent tokens and the solver's NFE.
+sizes and its family module (``families/<name>.py``) the FLOPs of one
+forward; a request gives the latent tokens and the solver's NFE.
 """
 
 from __future__ import annotations
@@ -28,26 +29,11 @@ def peaks(device_kind: str) -> dict:
                        f"known: {sorted(PEAKS)}") from None
 
 
-def dit_forward_flops(model: dict, tokens: int) -> int:
-    """FLOPs (2 per multiply-add) of one backbone forward over one latent
-    of ``tokens`` tokens: the dense projections (q, k, v, o and the MLP),
-    the S^2 attention (scores and the weighted sum of values), the adaLN
-    modulation and the input, output and time-embedding projections.
-    Norms, softmax and elementwise work are not counted."""
-    d, L = model["d_model"], model["n_layers"]
-    H, hd, F = model["n_heads"], model["head_dim"], model["d_ff"]
-    dz, temb = model["latent_dim"], model["time_embed_dim"]
-    dense = 2 * tokens * L * (4 * d * H * hd + 2 * d * F)
-    attention = 2 * L * 2 * tokens * tokens * H * hd
-    adaln = 2 * L * d * 6 * d
-    io = 2 * tokens * dz * d * 2 + 2 * (temb * d + d * d)
-    return dense + attention + adaln + io
-
-
-def sample_flops(model: dict, tokens: int, nfe: int, guided: bool) -> int:
+def sample_flops(forward_flops: int, nfe: int, guided: bool) -> int:
     """Model FLOPs of one served sample: NFE guided evaluations, each one
-    forward per branch (two under classifier-free guidance)."""
-    return nfe * (2 if guided else 1) * dit_forward_flops(model, tokens)
+    forward (of ``forward_flops``) per branch (two under classifier-free
+    guidance)."""
+    return nfe * (2 if guided else 1) * forward_flops
 
 
 def solver_step_bytes(tokens: int, latent_dim: int, history: int,

@@ -1,8 +1,9 @@
 """Backbone, attention (``models/attention.py`` under the program's
 ``attention`` name scope): the S^2 FLOPs of the forwards the device
-executed in the window, padded lanes included (``2 L 2 S^2 H hd`` per
-forward: the scores and the weighted sum of values), over the device
-time of the operations under that scope times the bf16 peak
+executed in the window, padded lanes included (the family's
+``attention_flops`` at the latent's tokens; DiT's ``2 L 2 S^2 H hd``:
+the scores and the weighted sum of values), over the device time of the
+operations under that scope times the bf16 peak
 (``spans.scope_seconds``)."""
 
 from benchmarks.chip import spans
@@ -15,7 +16,6 @@ def read(run):
     if seconds <= 0:
         return None
     m = run.cell.config["model"]
-    s = m["latent_tokens"]
-    flops = 2 * m["n_layers"] * 2 * s * s * m["n_heads"] * m["head_dim"]
+    flops = run.cell.family.attention_flops(m, m["latent_tokens"])
     return 100.0 * run.forwards * flops / (
         seconds * run.peaks["bf16_flops"])

@@ -3,28 +3,38 @@
     python3 -m benchmarks.chip.run --workload <cell> --seed <n> \\
         --seconds <s> --trace <0|1>
 
-A run builds the cell's DiT weights on the device from the seed (one
-jitted program), wraps them in the server's guided eps ``Denoiser``,
-warms the buckets its traffic uses, then drives ``ServeEngine.submit``
-and ``ServeEngine.step`` from its own loop for ``--seconds``: an open
-loop that submits each request when it is due, or a backlog kept a fixed
-depth. Once the window has closed it reads the device's peak memory,
-frees the server, recomputes a sample of the served requests with the
-plain reference (``reference.py``) and compares, against
-``limits/<cell>.json``: the median and the widest over the sample of
-each answer's relative L2 gap, and the share of the served values that
-are exact bfloat16 numbers (the configurations state a float32 solver
-state). The last line of standard output is one JSON object:
-``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's
+A run builds the cell's weights on the device from the seed (one jitted
+program), wraps the program's network with them in the server's guided
+``Denoiser``, warms the buckets its traffic uses, then drives
+``ServeEngine.submit`` and ``ServeEngine.step`` from its own loop for
+``--seconds``: an open loop that submits each request when it is due, or
+a backlog kept a fixed depth. Once the window has closed it reads the
+device's peak memory, frees the server, recomputes a sample of the
+served requests with the plain reference (``reference.py``) and
+compares, against ``limits/<cell>.json``: the median and the widest over
+the sample of each answer's relative L2 gap, and the share of the served
+values that are exact bfloat16 numbers (the configurations state a
+float32 solver state). The last line of standard output is one JSON
+object: ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's
 end-to-end metrics, or with ``--trace 1`` its per-layer ones),
 ``device``, with ``--trace 1`` a ``breakdown``, and last ``checks``,
-each compared number beside its limit. A run that finds no TPU, or
-fewer chips than the cell asks for, prints no result and exits 2.
+each compared number beside its limit. A run that finds no TPU, or fewer
+chips than the cell asks for, prints no result and exits 2.
 
-Everything a cell needs is found by name: ``BENCHMARK.json`` names the
-cell's configuration file, its traffic (``traffic/<name>.json``), its
-per-layer metrics (``metrics/<name>.py``, each a ``read(run)`` that
+Everything a cell needs is found by name (``resolve``): ``BENCHMARK.json``
+names the cell's configuration file, its traffic (``traffic/<name>.json``),
+its per-layer metrics (``metrics/<name>.py``, each a ``read(run)`` that
 returns a number or None) and this directory's ``limits/<cell>.json``.
+The configuration file names its denoiser family under ``family``
+(``families/<family>.py``: the weight tree, the program under test, the
+requests' conditioning, the reference backbone and the FLOP counts) and
+its noise schedule under ``schedule.kind`` (``schedules/<kind>.py``, which
+the reference's SA-Solver tables are built on). A configuration without
+``family``, or one that names a family or schedule that is not there,
+stops ``resolve`` with the path it looked for. So a second architecture
+is a family file, perhaps a schedule file, a configuration, a traffic
+file, a limits file and ``BENCHMARK.json`` entries: no existing file
+changes.
 """
 
 from __future__ import annotations
@@ -35,13 +45,15 @@ T_IMPORT = time.perf_counter()
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
+
+from . import modules  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -65,6 +77,7 @@ class Cell:
     end_to_end: list
     per_layer: list
     data_dir: str
+    family: object       # the configuration's families/<family>.py
 
 
 def _load(path: str) -> dict:
@@ -83,30 +96,32 @@ def resolve(workload: str, bench_path: str | None = None,
         raise SystemExit(f"no workload {workload!r} in {bench_path}; "
                          f"have {sorted(cells)}")
     w = cells[workload]
-    conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    conf_path = os.path.join(
+        root, {c["name"]: c for c in bench["configs"]}[w["config"]]["file"])
+    config = _load(conf_path)
+    if "family" not in config:
+        raise SystemExit(f"{conf_path} names no family: give it "
+                         f"\"family\": <name>, the module "
+                         f"{os.path.join(data_dir, 'families', '<name>.py')}")
+    family = modules.load(data_dir, "families", config["family"])
+    modules.load(data_dir, "schedules", config["schedule"]["kind"])
 
     def mine(metric):
         return workload in metric.get("workloads", [workload])
 
     return Cell(
-        name=workload, chips=int(w["chips"]),
-        config=_load(os.path.join(root, conf["file"])),
+        name=workload, chips=int(w["chips"]), config=config,
         traffic=_load(os.path.join(data_dir, "traffic",
                                    w["traffic"] + ".json")),
         limits=_load(os.path.join(data_dir, "limits", workload + ".json")),
         end_to_end=[m for m in bench["end_to_end"] if mine(m)],
         per_layer=[m for m in bench["per_layer"] if mine(m)],
-        data_dir=data_dir)
+        data_dir=data_dir, family=family)
 
 
 def reader(data_dir: str, name: str):
     """The ``read`` function of ``metrics/<name>.py``."""
-    path = os.path.join(data_dir, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return modules.load(data_dir, "metrics", name).read
 
 
 # ------------------------------------------------------------ counters
@@ -146,40 +161,19 @@ class Server:
     executables: list = dataclasses.field(default_factory=list)
 
 
-def program_config(conf: dict):
-    """The program's LMConfig at the configuration file's sizes."""
-    from repro.configs import get_config
-    m = conf["model"]
-    return dataclasses.replace(
-        get_config(conf["program"]["arch"]),
-        n_layers=m["n_layers"], d_model=m["d_model"], n_heads=m["n_heads"],
-        n_kv_heads=m["n_heads"], head_dim=m["head_dim"], d_ff=m["d_ff"],
-        vocab_size=m["vocab_size"], denoiser_latent=m["latent_dim"])
-
-
 def build_server(cell: Cell, params, seed: int,
                  precision: str | None = None) -> Server:
-    """The server under test. ``precision`` overrides the solver's
-    default precision policy (only the control does)."""
-    import jax
-    from repro.core import Denoiser, get_schedule
+    """The server under test: the family's program network in a guided
+    ``Denoiser``. ``precision`` overrides the solver's default precision
+    policy (only the control does)."""
+    from repro.core import Denoiser
     from repro.core.samplers import SamplerSpec
-    from repro.launch.sample import as_prediction_network
-    from repro.models import build_model
 
-    from . import weights as weights_mod
     conf, tr = cell.config, cell.traffic
-    model = build_model(program_config(conf))
-    want = weights_mod.shapes(conf["model"])
-    have = jax.tree.map(lambda d: tuple(d.shape), model.param_defs(),
-                        is_leaf=lambda d: hasattr(d, "init"))
-    if want != have:
-        raise SystemExit(f"the program's parameter tree {have} is not the "
-                         f"benchmark's {want}")
-    schedule = get_schedule(conf["program"]["schedule"])
+    network, schedule, null_cond = cell.family.program(conf)
     pred = conf["program"]["prediction"]
-    den = Denoiser(as_prediction_network(model, schedule, pred), schedule,
-                   prediction=pred, guidance=True, params=params)
+    den = Denoiser(network, schedule, prediction=pred, guidance=True,
+                   params=params, null_cond=null_cond)
     c = tr["request"]
     spec = SamplerSpec.from_nfe(
         c["sampler"], c["nfe"], schedule=schedule,
@@ -239,10 +233,10 @@ def warm(server: Server, cell: Cell) -> dict:
     from repro.core.samplers import build_plan, warmup
 
     from .traffic import Request
-    dz = server.shape[1]
+    proto = cell.family.cond_proto(cell.config["model"])
+    cond = jax.tree.map(lambda s: np.zeros(s.shape, s.dtype), proto)
     split = {}
     rid = WARM_RID
-    cond = np.zeros(dz, np.float32)
     if server.scheduler == "step":
         # two running batches, so a join, a merge and a migration all run
         t = time.perf_counter()
@@ -260,7 +254,6 @@ def warm(server: Server, cell: Cell) -> dict:
                         ("_aot_step", "_aot_join", "_aot_copy"))
             if aot is not None)
         return split
-    proto = jax.ShapeDtypeStruct((dz,), np.float32)
     for b in server.buckets:
         t = time.perf_counter()
         server.executables.append(warmup(
@@ -428,7 +421,7 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, *,
     split = {"import_s": now() - T_IMPORT}
 
     t = now()
-    params = weights.make(model, conf["weight_std"], seed)
+    params = weights.make(cell.family, model, conf["weight_std"], seed)
     jax.block_until_ready(params)
     split["weights_s"] = now() - t
     server = build_server(cell, params, seed, precision)
@@ -447,15 +440,15 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, *,
         opts.python_tracer_level = 0
         jax.profiler.start_trace(TRACE_DIR, profiler_options=opts)
     arrivals = tr["arrivals"]
+    draw = functools.partial(cell.family.conds, tr, model)
     steps, gcs = [], GcPauses()
     with gcs, _span("window"):
         if arrivals["kind"] == "backlog":
-            gen = traffic_mod.Backlog(tr, seed, model["latent_dim"])
+            gen = traffic_mod.Backlog(tr, seed, draw)
             records, events = backlog_loop(server, gen, seconds, now, steps)
             demand = [(0.0, events[-1][0] if events else seconds)]
         else:
-            reqs = traffic_mod.open_loop(tr, seed, seconds,
-                                         model["latent_dim"])
+            reqs = traffic_mod.open_loop(tr, seed, seconds, draw)
             records, demand = open_loop(server, reqs, seconds, now, steps)
             events = []
     if trace:
@@ -475,7 +468,7 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, *,
         "compiles": window_compiles}), flush=True)
 
     tokens = model["latent_tokens"]
-    fwd = counts.dit_forward_flops(model, tokens)
+    fwd = cell.family.forward_flops(model, tokens)
     lane_steps = after["lane_steps"] - before["lane_steps"]
     spec = server.spec
     per_lane_step = 1.0 if server.scheduler == "step" else \
@@ -488,7 +481,7 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, *,
         - before["active_lane_steps"],
         forwards=int(round(lane_steps * per_lane_step)) * 2,
         forward_flops=fwd,
-        sample_flops=counts.sample_flops(model, tokens, spec.nfe, True),
+        sample_flops=counts.sample_flops(fwd, spec.nfe, True),
         solver_bytes=counts.solver_step_bytes(
             tokens, model["latent_dim"],
             max(spec.predictor_order, spec.corrector_order)),
@@ -520,7 +513,8 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, *,
         r.x0 = None
     del server
     gc.collect()
-    ref_kw = dict(rids=[r.rid for r in sample],
+    ref_kw = dict(family=cell.family, data_dir=cell.data_dir,
+                  rids=[r.rid for r in sample],
                   conds=[r.cond for r in sample],
                   scales=[scale] * len(sample), noise_seed=noise_seed,
                   solve_seed=solve_seed, tokens=tokens)

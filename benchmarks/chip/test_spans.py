@@ -20,7 +20,7 @@ import types
 
 import pytest
 
-from benchmarks.chip import counts, run, spans, trace
+from benchmarks.chip import counts, modules, run, spans, trace
 
 TESTDATA = os.path.join(run.HERE, "testdata")
 NEW = ("loop_host_ms.tick", "loop_host_ms.microbatch", "queue_wait_p95_s",
@@ -50,7 +50,9 @@ def _run(forwards):
         model = json.load(f)["model"]
     return types.SimpleNamespace(
         trace={}, forwards=forwards, peaks=counts.peaks("TPU v5 lite"),
-        cell=types.SimpleNamespace(config={"model": model}))
+        cell=types.SimpleNamespace(
+            config={"model": model},
+            family=modules.load(run.HERE, "families", "dit")))
 
 
 def _read(name, r):
