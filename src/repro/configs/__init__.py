@@ -65,6 +65,8 @@ ARCHS = (
     # the paper's own denoiser architectures
     "dit-xl-2",
     "dit-s",
+    # the joint two-stream denoiser of Stable Diffusion 3
+    "sd3-medium",
 )
 
 _MODULES = {name: name.replace("-", "_") for name in ARCHS}

@@ -32,6 +32,7 @@ from .samplers import (
 from .schedules import (
     EDMSchedule,
     NoiseSchedule,
+    RectifiedFlowSchedule,
     VESchedule,
     VPCosineSchedule,
     VPLinearSchedule,
@@ -63,6 +64,7 @@ __all__ = [
     "VPCosineSchedule",
     "VESchedule",
     "EDMSchedule",
+    "RectifiedFlowSchedule",
     "get_schedule",
     "timestep_grid",
     "TauSchedule",

@@ -8,10 +8,12 @@ v-prediction — and are usually served under classifier-free guidance with
 per-request conditioning. :class:`Denoiser` closes that gap:
 
 - **prediction-type conversion** — ``convert_prediction`` maps any of
-  ``eps``/``x0``/``v`` to any other in-graph using the schedule's
-  ``alpha_t``/``sigma_t`` at the (traced) evaluation time, via the
-  identities of ``x_t = alpha_t x_0 + sigma_t eps`` and
-  ``v = alpha_t eps - sigma_t x_0``.
+  ``eps``/``x0``/``v``/``flow`` to any other in-graph using the
+  schedule's ``alpha_t``/``sigma_t`` (and their time derivatives, for
+  ``flow``) at the (traced) evaluation time, via the identities of
+  ``x_t = alpha_t x_0 + sigma_t eps``, ``v = alpha_t eps - sigma_t x_0``
+  and the flow velocity ``u = dx_t/dt = alpha_t' x_0 + sigma_t' eps``
+  (rectified flow: ``u = eps - x_0``).
 - **classifier-free guidance** — the cond and uncond branches are fused
   into ONE batched network evaluation (a stacked leading axis of 2, vmap
   over the network), then combined as ``(1 - s) * uncond + s * cond``.
@@ -56,12 +58,13 @@ __all__ = [
 ]
 
 #: canonical prediction-type names (aliases: "data"/"x0", "noise"/"eps")
-PREDICTION_TYPES = ("x0", "eps", "v")
+PREDICTION_TYPES = ("x0", "eps", "v", "flow")
 
 _ALIASES = {
     "data": "x0", "x0": "x0",
     "noise": "eps", "eps": "eps", "epsilon": "eps",
     "v": "v", "v_prediction": "v",
+    "flow": "flow",
 }
 
 
@@ -88,6 +91,8 @@ def convert_prediction(pred: jnp.ndarray, x: jnp.ndarray, t,
     src, dst = canonical_prediction(src), canonical_prediction(dst)
     if src == dst:
         return pred
+    if "flow" in (src, dst):
+        return _convert_flow(pred, x, t, src, dst, schedule)
     a = schedule.alpha_j(t)
     s = schedule.sigma_j(t)
     if dst == "x0":
@@ -102,6 +107,21 @@ def convert_prediction(pred: jnp.ndarray, x: jnp.ndarray, t,
     if src == "x0":
         return a * (x - a * pred) / s - s * pred
     return a * pred - s * (x - s * pred) / a             # src == "eps"
+
+
+def _convert_flow(pred, x, t, src, dst, schedule):
+    """Conversions to and from the flow velocity ``u = a' x_0 + s' eps``:
+    with ``x = a x_0 + s eps``, ``x_0 = (s' x - s u) / D`` and ``eps =
+    (a u - a' x) / D`` for ``D = a s' - s a'`` (rectified flow: D = 1,
+    ``x_0 = x - t u``)."""
+    a, s = schedule.alpha_j(t), schedule.sigma_j(t)
+    da, ds = schedule.d_alpha_j(t), schedule.d_sigma_j(t)
+    if src == "flow":
+        x0 = (ds * x - s * pred) / (a * ds - s * da)
+        return convert_prediction(x0, x, t, "x0", dst, schedule)
+    x0 = convert_prediction(pred, x, t, src, "x0", schedule)
+    eps = convert_prediction(pred, x, t, src, "eps", schedule)
+    return da * x0 + ds * eps
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -136,7 +156,7 @@ class Denoiser:
         schedule: the noise schedule whose ``alpha_t``/``sigma_t`` drive
             the in-graph prediction conversion. Must match the plan's.
         prediction: the network's output convention — ``"eps"``/``"x0"``/
-            ``"v"`` (aliases ``"noise"``/``"data"`` accepted).
+            ``"v"``/``"flow"`` (aliases ``"noise"``/``"data"`` accepted).
         guidance: enable classifier-free guidance. The executor traces a
             doubled-lane fused network evaluation and combines branches
             with the per-call (traced) ``guidance_scale``.

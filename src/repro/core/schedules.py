@@ -19,6 +19,7 @@ import dataclasses
 import math
 from typing import Callable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -28,6 +29,7 @@ __all__ = [
     "VPCosineSchedule",
     "VESchedule",
     "EDMSchedule",
+    "RectifiedFlowSchedule",
     "timestep_grid",
     "get_schedule",
 ]
@@ -79,6 +81,16 @@ class NoiseSchedule:
 
     def lam_j(self, t):
         return self.log_alpha_j(t) - self.log_sigma_j(t)
+
+    def d_alpha_j(self, t):
+        """d alpha_t / dt, elementwise (forward-mode derivative)."""
+        t = jnp.asarray(t, jnp.float32)
+        return jax.jvp(self.alpha_j, (t,), (jnp.ones_like(t),))[1]
+
+    def d_sigma_j(self, t):
+        """d sigma_t / dt, elementwise (forward-mode derivative)."""
+        t = jnp.asarray(t, jnp.float32)
+        return jax.jvp(self.sigma_j, (t,), (jnp.ones_like(t),))[1]
 
     # ---- defaults ----------------------------------------------------------
     #: default integration span [t_end, t_start]
@@ -249,6 +261,55 @@ class VESchedule(NoiseSchedule):
 EDMSchedule = VESchedule
 
 
+@dataclasses.dataclass(frozen=True)
+class RectifiedFlowSchedule(NoiseSchedule):
+    """Rectified flow (Esser et al., arXiv:2403.03206): the straight path
+    ``x_t = (1 - t) x_0 + t eps``, so alpha_t = 1 - t, sigma_t = t and
+    lambda_t = log((1 - t) / t), t in (0, 1).
+
+    lambda is infinite at both ends, so a span must lie strictly inside
+    (0, 1). SD3's timestep ``shift`` re-spaces its Euler sampler's grid
+    and is no property of the schedule: a log-SNR grid replaces it.
+    """
+
+    t_start: float = 0.999
+    t_end: float = 1e-3
+
+    def validate_span(self, t_start: float, t_end: float) -> None:
+        if not 0.0 < t_end < t_start < 1.0:
+            raise ValueError(
+                f"rectified flow needs 0 < t_end < t_start < 1, got "
+                f"t_start={t_start:g}, t_end={t_end:g}: log-SNR is "
+                f"infinite at t = 0 and t = 1")
+
+    def log_alpha(self, t):
+        return np.log1p(-np.asarray(t, dtype=np.float64))
+
+    def log_sigma(self, t):
+        return np.log(np.asarray(t, dtype=np.float64))
+
+    def t_of_lam(self, lam):
+        return 1.0 / (1.0 + np.exp(np.asarray(lam, dtype=np.float64)))
+
+    def alpha(self, t):
+        return 1.0 - np.asarray(t, dtype=np.float64)
+
+    def sigma(self, t):
+        return np.asarray(t, dtype=np.float64)
+
+    def log_alpha_j(self, t):
+        return jnp.log1p(-t)
+
+    def log_sigma_j(self, t):
+        return jnp.log(t)
+
+    def alpha_j(self, t):
+        return 1.0 - t
+
+    def sigma_j(self, t):
+        return t
+
+
 def timestep_grid(
     schedule: NoiseSchedule,
     n_steps: int,
@@ -296,6 +357,7 @@ _REGISTRY: dict[str, Callable[[], NoiseSchedule]] = {
     "vp_cosine": VPCosineSchedule,
     "ve": VESchedule,
     "edm": VESchedule,
+    "rectified_flow": RectifiedFlowSchedule,
 }
 
 

@@ -29,8 +29,10 @@ GQA: k/v carry K heads; q head h reads kv head h // (H // K), so no
 host-side broadcast materializes [B, T, H, hd].
 
 Block sizes are a function of the shapes (``_block_sizes``), chosen by
-a sweep on a TPU v5e at DiT-XL/2's shapes only (16 heads of 72,
-non-causal, 256 and 1024 tokens; PERF.md).
+sweeps on a TPU v5e at DiT-XL/2's shapes (16 heads of 72, non-causal,
+256 and 1024 tokens) and at SD3 Medium's joint attention (24 heads of
+64, 4096 image + 154 text tokens, a ragged 4250; PERF.md): one KV block
+over every key wherever K and V fit in VMEM.
 """
 
 from __future__ import annotations
@@ -51,14 +53,34 @@ NEG_INF = -2.0**30
 _VMEM_LIMIT = 100 * 2**20
 #: q rows x merged width of the largest q block
 _Q_BLOCK_ELEMS = 512 * 1152
+#: VMEM that one KV block over every key may take for K and V, each
+#: double-buffered (SD3 Medium's 4250 x 1536 bf16 take 52 MB)
+_KV_BLOCK_BYTES = 64 * 2**20
+#: float32 elements of one head's score tile, bq x bk
+_TILE_ELEMS = 1024 * 1024
 
 
-def _block_sizes(S: int, T: int, width: int) -> tuple[int, int]:
+def _block_sizes(S: int, T: int, width: int,
+                 itemsize: int = 2) -> tuple[int, int]:
     """(bq, bk) for S queries over T keys with ``width`` = H * hd merged
-    q columns: one KV block up to 1024 keys, else 512; q blocks of up to
-    512 rows at the DiT's width, fewer where the heads are wider."""
-    bk = T if T <= 1024 else 512
-    rows = _Q_BLOCK_ELEMS // width
+    columns of ``itemsize``-byte operands: one KV block over all T keys
+    (a plain softmax, no running statistics) while K and V, double-
+    buffered, fit in ``_KV_BLOCK_BYTES``, else blocks of 512 keys; q
+    blocks of up to 512 rows at the DiT's width, fewer where the heads
+    are wider or a head's score tile would pass ``_TILE_ELEMS``.
+
+    Measured on a TPU v5e, one layer over 8 sequences of 24 heads of 64
+    (PERF.md): at SD3 Medium's joint 4250 tokens this gives (128, 4250)
+    at 13.0-13.1 ms, against 13.8 at (256, 4250), which the score-tile
+    cap refuses, no fit in VMEM at (512, 4250), 17.6 at (128, 2176),
+    23.5 at (128, 1024) and 28.7 at (256, 512). At 5440 tokens, where K
+    and V take 63.75 MiB, one block (23.9 ms) still beats 2720 (25.1)
+    and 1024 (36.4). At 6144 (72 MiB) one block also won (27.4 against
+    38.8 at 1024), so the KV bound errs low; the 512-key fallback past
+    it was not timed. DiT-XL/2's 256 and 1024 tokens get (256, 256) and
+    (512, 1024), as swept at their shapes."""
+    bk = T if 4 * T * width * itemsize <= _KV_BLOCK_BYTES else 512
+    rows = min(_Q_BLOCK_ELEMS // width, _TILE_ELEMS // bk)
     bq = S if S <= rows else max(128, 1 << (rows.bit_length() - 1))
     return bq, bk
 
@@ -161,10 +183,10 @@ def flash_attention(q, k, v, *, causal: bool = True, dtype=None,
         interpret = jax.default_backend() != "tpu"
     B, S, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
-    dq, dk = _block_sizes(S, T, H * hd)
+    dtype = dtype or q.dtype
+    dq, dk = _block_sizes(S, T, H * hd, jnp.dtype(dtype).itemsize)
     bq = min(bq or dq, S)
     bk = min(bk or dk, T)
-    dtype = dtype or q.dtype
     q = (q.astype(jnp.float32) * (1.0 / math.sqrt(hd))).astype(dtype)
     q = q.reshape(B, S, H * hd)
     k = k.astype(dtype).reshape(B, T, K * hd)

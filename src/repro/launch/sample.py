@@ -78,6 +78,25 @@ def as_prediction_network(model, schedule, prediction: str):
     return network
 
 
+def as_conditioned_network(model, schedule, prediction: str):
+    """The sibling of :func:`as_prediction_network` for a backbone that
+    takes its own conditioning (``model.denoise(params, z, t, cond)``,
+    predicting ``model.cfg.prediction``): ``cond`` goes to the backbone
+    whole, and the output is converted in-graph to ``prediction``. The
+    unconditional branch is the Denoiser's ``null_cond``."""
+
+    def network(params, x, t, cond):
+        lane = x.ndim == 2
+        if lane:
+            x1, cond = x[None], jax.tree.map(lambda c: c[None], cond)
+        out = model.denoise(params, x1 if lane else x, t, cond)
+        out = out[0] if lane else out
+        return convert_prediction(out, x, t, model.cfg.prediction,
+                                  prediction, schedule)
+
+    return network
+
+
 def as_cached_network(model, schedule, prediction: str):
     """The feature-cached twin of :func:`as_prediction_network`: a
     :class:`CachedNetwork` whose ``call`` threads the mid-block feature

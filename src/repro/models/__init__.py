@@ -1,4 +1,5 @@
-"""Model zoo: dense/MoE/MLA transformers, RWKV6, Mamba2/Zamba2 hybrid.
+"""Model zoo: dense/MoE/MLA transformers, RWKV6, Mamba2/Zamba2 hybrid, and
+the two-stream MMDiT denoiser (SD3).
 
 All models share a duck-typed API:
     param_defs() -> ParamDef tree (stacked [L, ...] for scanned layers)
@@ -15,6 +16,7 @@ All models share a duck-typed API:
 from .attention import AttentionConfig, MLAConfig
 from .common import ParamDef, abstract_params, init_params, specs_for
 from .mamba2 import Mamba2Config, Zamba2, Zamba2Config
+from .mmdit import MMDiT, MMDiTConfig
 from .moe import MoEConfig
 from .rwkv6 import RWKV6, RWKV6Config
 from .transformer import LMConfig, TransformerLM
@@ -22,6 +24,7 @@ from .transformer import LMConfig, TransformerLM
 __all__ = [
     "AttentionConfig", "MLAConfig", "MoEConfig", "LMConfig", "TransformerLM",
     "RWKV6", "RWKV6Config", "Mamba2Config", "Zamba2", "Zamba2Config",
+    "MMDiT", "MMDiTConfig",
     "ParamDef", "init_params", "abstract_params", "specs_for", "build_model",
 ]
 
@@ -33,4 +36,6 @@ def build_model(cfg):
         return RWKV6(cfg)
     if isinstance(cfg, Zamba2Config):
         return Zamba2(cfg)
+    if isinstance(cfg, MMDiTConfig):
+        return MMDiT(cfg)
     raise TypeError(f"unknown config type {type(cfg).__name__}")

@@ -365,13 +365,18 @@ def mlp_defs(d_model: int, d_ff: int, gated: bool) -> dict:
 
 
 def mlp_apply(p: dict, x, act: str, gated: bool):
+    """The MLP; biases ``bi`` and ``bo``, where ``p`` holds them, follow
+    the first and the last projection."""
     f = ACTIVATIONS[act]
     h = x @ p["wi"]
+    if "bi" in p:
+        h = h + p["bi"]
     if gated:
         h = f(x @ p["wg"]) * h
     else:
         h = f(h)
-    return h @ p["wo"]
+    out = h @ p["wo"]
+    return out + p["bo"] if "bo" in p else out
 
 
 def softmax_cross_entropy(logits, labels, mask=None):

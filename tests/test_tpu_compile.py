@@ -8,9 +8,11 @@ chip and check that the compiled program holds the kernel
 (256 x 16) alone and under the serving lanes' ``vmap`` (8 lanes,
 per-lane coefficients as in the step function), a latent whose size is
 not a multiple of 128, non-causal flash attention at DiT-XL/2's
-head_dim 72 at 256 and 1024 tokens, and a DiT-XL/2-width denoiser layer
-whose attention the program dispatches to that kernel on one chip, and
-keeps on the jnp path with its lanes split over the four chips.
+head_dim 72 at 256 and 1024 tokens and at SD3 Medium's joint layout
+(4250 tokens, 24 heads of 64), a DiT-XL/2-width denoiser layer whose
+attention the program dispatches to that kernel on one chip, and keeps
+on the jnp path with its lanes split over the four chips, and two SD3
+Medium-width MMDiT blocks whose joint attentions are the kernel.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
@@ -166,3 +168,46 @@ def _dit_layer_attention(replicated, lanes_sharding, tokens):
              if "tpu_custom_call" in ln and " custom-call(" in ln]
     att = [op for op in _OP_NAME.findall(text) if _in_attention(op)]
     return calls, any("bskgd,btkd->bkgst" in op for op in att)
+
+
+def test_flash_attention_compiles_at_the_joint_layout(one_chip):
+    """SD3 Medium's joint attention: 4096 image and 154 text tokens, a
+    ragged 4250, 24 heads of 64 merged into 1536 columns, at the blocks
+    ``_block_sizes`` picks for it."""
+    fn = lambda q, k, v: flash_attention(q, k, v, causal=False,
+                                         dtype=jnp.bfloat16, interpret=False)
+    q = jax.ShapeDtypeStruct((2, 4250, 24, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    assert "tpu_custom_call" in compiled_text(fn, q, q, q)
+
+
+def test_mmdit_denoise_runs_joint_attention_as_the_kernel(one_chip,
+                                                          monkeypatch):
+    """A 2-block SD3 Medium-width MMDiT (a full joint block and the
+    pre-only last one) over 4096 image and 154 text tokens, for 4 lanes
+    x the CFG pair (vmapped, as the serving lanes call it), compiled for
+    the chip: both joint attentions are the Pallas kernel under the
+    ``attention`` scope, and the text stream's ops carry the
+    ``context_stream`` scope."""
+    from repro.configs import get_config
+    from repro.models import abstract_params, build_model
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = build_model(dataclasses.replace(get_config("sd3-medium"),
+                                            n_layers=2))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        abstract_params(model.param_defs()))
+    s = lambda *sh: jax.ShapeDtypeStruct(sh, jnp.float32, sharding=one_chip)
+
+    def lanes(p, z, t, c):
+        one = lambda zz, cc: model.denoise(
+            p, zz[None], t, jax.tree.map(lambda a: a[None], cc))[0]
+        return jax.vmap(jax.vmap(one))(z, c)
+
+    text = compiled_text(lanes, params, s(4, 2, 4096, 64), s(),
+                         {"seq": s(4, 2, 154, 4096), "pooled": s(4, 2, 2048)})
+    calls = [_OP_NAME.search(ln).group(1) for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(calls) == 2 and all(_in_attention(op) for op in calls)
+    assert any("context_stream" in re.split(r"[/();]", op)
+               for op in _OP_NAME.findall(text))
