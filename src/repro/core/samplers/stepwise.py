@@ -213,7 +213,8 @@ def fresh_carry(plan: SamplerPlan, batch: int, shape, dtype,
 # ------------------------------------------------------------ compile cache
 _STEP_CACHE: OrderedDict = OrderedDict()
 _STEP_CACHE_MAX = 64
-_STEP_STATS = {"hits": 0, "misses": 0, "evictions": 0, "aot_fallbacks": 0}
+_STEP_STATS = {"hits": 0, "misses": 0, "evictions": 0, "aot_fallbacks": 0,
+               "weight_casts": 0}
 _STEP_TOKEN_IDX = 7  # position of the model token inside a step key
 
 
@@ -223,6 +224,7 @@ def stepwise_cache_stats() -> dict:
 
 def clear_stepwise_cache() -> None:
     _STEP_CACHE.clear()
+    _ROUNDED.clear()
     for k in _STEP_STATS:
         _STEP_STATS[k] = 0
 
@@ -238,6 +240,93 @@ def _on_model_death(ref) -> None:
                 if _token_matches(k[_STEP_TOKEN_IDX], ref)]:
         if _STEP_CACHE.pop(key, None) is not None:
             _STEP_STATS["evictions"] += 1
+
+
+# ------------------------------------------------------ weight copies
+#: bf16 copies of f32 weights, by the id of the f32 array: (a weak
+#: reference to that array, its copy). An entry goes when its array goes.
+#: Keyed by leaf, since a params dict takes no weak reference.
+_ROUNDED: dict = {}
+
+
+def _dots_round_to_bf16() -> bool:
+    """Whether a default-precision f32 dot rounds both operands to bf16
+    before the matrix unit, as the TPU backend does. The CPU multiplies
+    in full f32, so a bf16 copy of a weight would lower its precision."""
+    return jax.default_backend() == "tpu"
+
+
+@jax.jit
+def _round_bf16(weights):
+    return [w.astype(jnp.bfloat16) for w in weights]
+
+
+def _forget(key):
+    def drop(ref):
+        if _ROUNDED.get(key, (None,))[0] is ref:
+            del _ROUNDED[key]
+    return drop
+
+
+@jax.tree_util.register_pytree_node_class
+class _StepWeights:
+    """A model's weights as a step program takes them: the leaves of
+    ``treedef``, those flagged in ``rounded`` held as bf16 copies of
+    f32 arrays."""
+
+    __slots__ = ("leaves", "treedef", "rounded")
+
+    def __init__(self, leaves, treedef, rounded):
+        self.leaves, self.treedef, self.rounded = leaves, treedef, rounded
+
+    def tree_flatten(self):
+        return self.leaves, (self.treedef, self.rounded)
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(list(leaves), *aux)
+
+    def f32(self):
+        """The weight tree with each copy upcast to f32. Its only readers
+        are default-precision dots, which round it back to the same bf16
+        operand, and XLA folds the two converts away."""
+        return jax.tree.unflatten(self.treedef, [
+            w.astype(jnp.float32) if r else w
+            for w, r in zip(self.leaves, self.rounded)])
+
+
+def _step_weights(model):
+    """The weights ``model``'s step program takes on each tick.
+
+    Where default-precision dots round f32 operands to bf16 (the TPU),
+    the leaves the network states as read only by such dots (its
+    ``dot_weights`` tree of bools) go as bf16 copies, made in one jitted
+    call the first time their f32 arrays are seen and counted in
+    ``_STEP_STATS["weight_casts"]``; so the per-tick rounding of the f32
+    matrices leaves the step program. Every other leaf, and every path
+    elsewhere, is the model's own weights unchanged."""
+    params = _model_params(model)
+    dots = getattr(getattr(model, "network", None), "dot_weights", None)
+    if params is None or dots is None or not _dots_round_to_bf16():
+        return params
+    leaves, treedef = jax.tree.flatten(params)
+    rounded = tuple(bool(d) and w.dtype == np.float32
+                    for w, d in zip(leaves, treedef.flatten_up_to(dots)))
+    out, missing = list(leaves), []
+    for i, r in enumerate(rounded):
+        if r:
+            entry = _ROUNDED.get(id(leaves[i]))
+            if entry and entry[0]() is leaves[i]:
+                out[i] = entry[1]
+            else:
+                missing.append(i)
+    if missing:
+        for i, c in zip(missing, _round_bf16([leaves[i] for i in missing])):
+            w = leaves[i]
+            _ROUNDED[id(w)] = (weakref.ref(w, _forget(id(w))), c)
+            out[i] = c
+        _STEP_STATS["weight_casts"] += 1
+    return _StepWeights(out, treedef, rounded)
 
 
 class StepFns:
@@ -273,7 +362,7 @@ class StepFns:
         return fn(*args)
 
     def step(self, arrays, carry):
-        params = _model_params(_deref_model(self.cell))
+        params = _step_weights(_deref_model(self.cell))
         return self._call(self._aot_step, self._step, arrays, carry, params)
 
     def join(self, arrays, carry, lane, x_T, keys, tol, min_i, scale,
@@ -296,7 +385,9 @@ class StepFns:
         return self._aot_step is not None
 
     def warm(self, arrays, carry, *, cond=None) -> None:
-        """AOT-compile step/join/copy against this batch's avals.
+        """AOT-compile step/join/copy against this batch's avals; the
+        step against the weights as ``_step_weights`` gives them, which
+        makes their bf16 copies where there are any.
 
         ``cond`` is the per-request conditioning prototype (no lane
         axis) — required when the carry has one. Idempotent.
@@ -307,7 +398,7 @@ class StepFns:
             lambda a: jax.ShapeDtypeStruct(tuple(a.shape),
                                            jnp.dtype(a.dtype)), t)
         arrays_s, carry_s = aval(arrays), aval(carry)
-        params_s = aval(_model_params(_deref_model(self.cell)))
+        params_s = aval(_step_weights(_deref_model(self.cell)))
         self._aot_step = self._step.lower(arrays_s, carry_s,
                                           params_s).compile()
         proto = jax.random.PRNGKey(0)
@@ -331,6 +422,8 @@ class StepFns:
 def _make_run_step(adapter, dadapter, cell, has_cond: bool, stream: bool,
                    has_fc: bool = False):
     def run_step(arrays, carry, params):
+        if isinstance(params, _StepWeights):
+            params = params.f32()
         m = _deref_model(cell)
         M = adapter.n_steps_of(arrays)
 

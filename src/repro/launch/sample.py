@@ -75,6 +75,9 @@ def as_prediction_network(model, schedule, prediction: str):
         x0 = x0[0] if lane else x0
         return convert_prediction(x0, x, t, "x0", prediction, schedule)
 
+    if hasattr(model, "dot_weights"):
+        # the leaves the step scheduler takes as bf16 copies on the TPU
+        network.dot_weights = model.dot_weights()
     return network
 
 

@@ -381,10 +381,10 @@ class TransformerLM:
 
     # ---- denoiser mode (SA-Solver integration) ------------------------
     def _tcond(self, dp, t, batch: int, cond):
-        """adaLN conditioning signal, kept f32 end to end: the bf16
-        precision policy casts *latents* only — quantizing ``t`` (or the
-        class/text conditioning vector) to bf16 collapses adjacent solver
-        timesteps (8 mantissa bits) and visibly biases the trajectory."""
+        """adaLN conditioning signal in f32 from an f32 ``t``: quantizing
+        ``t`` (or the cond vector) to bf16 collapses adjacent solver
+        timesteps. Its dots, ``tcond @ adaln`` too, run at default
+        precision, which on the TPU rounds both operands to bf16."""
         t = jnp.broadcast_to(jnp.asarray(t, jnp.float32), (batch,))
         temb = timestep_embedding(t, 256)
         tcond = jax.nn.silu(temb @ dp["t_mlp1"].astype(jnp.float32)) \
@@ -480,3 +480,14 @@ class TransformerLM:
         x = rms_norm(x, params["ln_f"])
         out = (x @ dp["out_proj"].astype(cfg.dtype)).astype(jnp.float32)
         return out, feats
+
+    def dot_weights(self) -> dict:
+        """A bool for each leaf of ``param_defs()``: True for the layer
+        stacks' matrices (adaLN, attention and MLP projections), which the
+        blocks read only as operands of default-precision matmuls, and
+        False for the norm gains and every leaf outside the stacks."""
+        return {k: jax.tree.map(
+                    lambda d, stack=k in ("blocks", "moe_blocks"):
+                    stack and len(d.shape) >= 3,
+                    v, is_leaf=lambda d: isinstance(d, ParamDef))
+                for k, v in self.param_defs().items()}

@@ -25,6 +25,10 @@ share one entry; lane-count changes do not), and the unregistered-family
 error path.
 """
 
+import dataclasses
+import gc
+import weakref
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -306,3 +310,121 @@ def test_adapter_reports_in_band_init():
     adapter = stepwise_adapter(_spec())
     assert adapter.i0 == -1  # SA warm-up eval runs as the first tick
     assert stepwise_adapter(_spec(name="ddim")).i0 == 0
+
+
+# ------------------------------------------------- step-path weight copies
+DIT_SHAPE = (16, 8)  # the smoke DiT's latent: 16 tokens x 8 channels
+
+
+def _dit(seed=0):
+    """A Denoiser over the 2-block smoke DiT, its f32 weights perturbed
+    off bf16 values (and the adaLN-zero projections off zero)."""
+    from repro.configs import get_smoke
+    from repro.core import Denoiser
+    from repro.launch.sample import as_prediction_network
+    from repro.models import build_model, init_params
+    model = build_model(get_smoke("dit-s"))
+    params = init_params(jax.random.PRNGKey(seed), model.param_defs(),
+                         jnp.float32)
+    params = jax.tree.map(
+        lambda p: p + 0.02 * jax.random.normal(jax.random.PRNGKey(seed + 1),
+                                               p.shape, p.dtype), params)
+    net = as_prediction_network(model, SCHED, "eps")
+    return Denoiser(net, SCHED, prediction="eps", params=params)
+
+
+def _dit_ticks(den, ticks=3, lanes=2):
+    """Warm a step function for ``den``, join two requests and run
+    ``ticks`` ticks; returns the carry's leaves as numpy arrays."""
+    plan = build_plan(_spec(n_steps=4))
+    fns = make_stepfns(plan, den, DIT_SHAPE, jnp.float32, lanes)
+    arrays = fns.adapter.arrays(plan)
+    carry = fresh_carry(plan, lanes, DIT_SHAPE, jnp.float32)
+    fns.warm(arrays, carry)
+    M = fns.adapter.n_steps_of(arrays)
+    for lane in range(lanes):
+        k = jax.random.PRNGKey(10 + lane)
+        carry = fns.join(arrays, carry, lane,
+                         jax.random.normal(k, DIT_SHAPE),
+                         jax.random.split(jax.random.fold_in(k, 1), M),
+                         0.0, 0, 1.0)
+    for _ in range(ticks):
+        carry, _ = fns.step(arrays, carry)
+    return [np.asarray(a) for a in jax.tree.leaves(carry)]
+
+
+@pytest.fixture
+def copies_on(monkeypatch):
+    """The step path as on a backend whose default-precision f32 dots
+    round to bf16 (the TPU)."""
+    from repro.core.samplers import stepwise
+    monkeypatch.setattr(stepwise, "_dots_round_to_bf16", lambda: True)
+    clear_stepwise_cache()
+    yield stepwise
+    clear_stepwise_cache()
+
+
+def test_weight_copy_tick_is_the_program_on_prerounded_weights(copies_on):
+    """With the copies on, a tick reads the copies upcast to f32: bit for
+    bit the unchanged step program on weights rounded to bf16 ahead of
+    time and held as f32, which is what a TPU's default dot feeds the
+    MXU from the f32 weights."""
+    den = _dit()
+    dots = den.network.dot_weights
+    assert any(jax.tree.leaves(dots)) and not all(jax.tree.leaves(dots))
+    got = _dit_ticks(den)
+    pre = jax.tree.map(
+        lambda p, d: p.astype(jnp.bfloat16).astype(jnp.float32) if d else p,
+        den.params, dots)
+    copies_on._dots_round_to_bf16 = lambda: False
+    ref = _dit_ticks(dataclasses.replace(den, params=pre))
+    assert all((a == b).all() for a, b in zip(got, ref))
+    # and the rounding is real: the f32 weights give another tick
+    full = _dit_ticks(den)
+    assert any((a != b).any() for a, b in zip(got, full))
+
+
+def test_weight_copy_made_once_per_weights_and_freed(copies_on):
+    den = _dit()
+    _dit_ticks(den, ticks=4)
+    s = stepwise_cache_stats()
+    assert s["weight_casts"] == 1, s
+    sw = copies_on._step_weights(den)
+    copy = weakref.ref(next(w for w, r in zip(sw.leaves, sw.rounded) if r))
+    assert stepwise_cache_stats()["weight_casts"] == 1
+    assert {str(w.dtype) for w, r in zip(sw.leaves, sw.rounded) if r} \
+        == {"bfloat16"}
+    assert all(w.dtype == jnp.float32 for w in jax.tree.leaves(den.params))
+    del sw
+    den2 = _dit(seed=5)
+    _dit_ticks(den2, ticks=2)
+    assert stepwise_cache_stats()["weight_casts"] == 2
+    n_copies = len(copies_on._ROUNDED)
+    del den
+    gc.collect()
+    assert copy() is None, "the old weights' copy outlived them"
+    assert 0 < len(copies_on._ROUNDED) < n_copies
+
+
+def test_weight_copy_off_on_cpu():
+    """On the CPU a default dot multiplies in full f32: the step program
+    takes the f32 weights unchanged and no copy is made."""
+    from repro.core.samplers import stepwise
+    clear_stepwise_cache()
+    den = _dit()
+    assert not stepwise._dots_round_to_bf16()
+    assert stepwise._step_weights(den) is den.params
+    _dit_ticks(den)
+    assert stepwise_cache_stats()["weight_casts"] == 0
+    assert not stepwise._ROUNDED
+
+
+def test_solve_path_makes_no_weight_copy(copies_on):
+    den = _dit()
+    plan = build_plan(_spec(n_steps=4))
+    keys = jax.random.split(jax.random.PRNGKey(0), 2)
+    xT = jax.random.normal(jax.random.PRNGKey(1), (2,) + DIT_SHAPE)
+    out = sample_batched(plan, den, xT, keys)
+    assert np.isfinite(np.asarray(out)).all()
+    assert stepwise_cache_stats()["weight_casts"] == 0
+    assert not copies_on._ROUNDED

@@ -211,3 +211,57 @@ def test_mmdit_denoise_runs_joint_attention_as_the_kernel(one_chip,
     assert len(calls) == 2 and all(_in_attention(op) for op in calls)
     assert any("context_stream" in re.split(r"[/();]", op)
                for op in _OP_NAME.findall(text))
+
+
+def _weight_converts(text, shapes):
+    """The converts in ``text`` whose result has the shape of a weight
+    stack in ``shapes`` (the [L, ...] leaves)."""
+    dims = {",".join(map(str, s)) for s in shapes}
+    return [ln for ln in text.splitlines() if " convert(" in ln
+            and re.search(r"= (?:bf16|f32)\[([\d,]+)\]", ln)
+            and re.search(r"= (?:bf16|f32)\[([\d,]+)\]", ln).group(1) in dims]
+
+
+def test_step_program_takes_weight_copies_without_converts(one_chip):
+    """The step scheduler's program for a 2-block DiT-XL/2-width
+    denoiser (8 lanes, CFG, the step cell's SA-Solver), compiled for the
+    chip: fed the f32 weights it rounds each weight stack to bf16 inside
+    (a convert per stack on every tick); fed the step path's copies,
+    upcast to f32 at its top, XLA folds the converts away and none of a
+    weight stack's shape is left."""
+    from repro.configs import dit_xl_2
+    from repro.core import Denoiser, get_schedule
+    from repro.core.samplers import (SamplerSpec, build_plan, fresh_carry,
+                                     make_stepfns)
+    from repro.core.samplers.stepwise import _StepWeights
+    from repro.launch.sample import as_prediction_network
+    from repro.models import abstract_params, build_model
+    model = build_model(dataclasses.replace(dit_xl_2.full(), n_layers=2))
+    sched = get_schedule("vp_linear")
+    on_chip = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = on_chip(abstract_params(model.param_defs()))
+    den = Denoiser(as_prediction_network(model, sched, "eps"), sched,
+                   prediction="eps", guidance=True, params=params)
+    spec = SamplerSpec.from_nfe("sa", 20, schedule=sched, predictor_order=3,
+                                corrector_order=1, tau=1.0, prediction="eps",
+                                guidance=True)
+    plan = build_plan(spec)
+    shape, cond = (256, dit_xl_2.LATENT_DIM), \
+        jnp.zeros((dit_xl_2.LATENT_DIM,), jnp.float32)
+    fns = make_stepfns(plan, den, shape, jnp.float32, 8, cond=cond)
+    arrays = on_chip(jax.eval_shape(lambda: fns.adapter.arrays(plan)))
+    carry = on_chip(jax.eval_shape(lambda: fresh_carry(
+        plan, 8, shape, jnp.float32, cond=cond)))
+    leaves, treedef = jax.tree.flatten(params)
+    rounded = tuple(treedef.flatten_up_to(den.network.dot_weights))
+    copies = _StepWeights(
+        [jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one_chip)
+         if r else a for a, r in zip(leaves, rounded)], treedef, rounded)
+    stacks = [a.shape for a, r in zip(leaves, rounded) if r]
+    assert len(stacks) == 7  # adaLN, q, k, v, o, MLP in and out
+    f32 = fns._step.lower(arrays, carry, params).compile().as_text()
+    bf16 = fns._step.lower(arrays, carry, copies).compile().as_text()
+    assert len(_weight_converts(f32, stacks)) >= len(stacks)
+    assert not _weight_converts(bf16, stacks)
