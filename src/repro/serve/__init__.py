@@ -71,7 +71,9 @@ never aborts another's work), bounded retry-with-degradation
 into the RNG streams, attempt 0 stays bitwise), consecutive-failure
 quarantine with cooldown, a straggler watchdog, and a seeded chaos
 harness (:class:`FaultPlan`/:class:`FaultInjector`) that exercises all
-of it deterministically. ``ServeEngine.health()`` is the poll surface.
+of it deterministically. Retry, quarantine and degradation are one
+:class:`FaultPolicy` (:mod:`repro.serve.policy`) that both schedulers
+share. ``ServeEngine.health()`` is the poll surface.
 
 Drivers: ``python -m repro.launch.serve --mode diffusion`` (full CLI),
 ``examples/serve_diffusion.py`` (thin client),
@@ -79,12 +81,13 @@ Drivers: ``python -m repro.launch.serve --mode diffusion`` (full CLI),
 ``benchmarks/bench_faults.py`` (goodput under an injected fault mix).
 """
 
-from .batching import (MicroBatch, PAD_RID, Request, bucket_key,
-                       choose_bucket, cond_struct, fold_keys,
-                       form_microbatches, retry_fold)
-from .continuous import ContinuousBatcher, RunningBatch, bucket_label
-from .engine import ServeEngine, ServeResult
+from .batching import (MicroBatch, PAD_RID, Request, ServeResult,
+                       bucket_key, bucket_label, choose_bucket, cond_struct,
+                       fold_keys, form_microbatches, retry_fold)
+from .continuous import ContinuousBatcher, RunningBatch
+from .engine import ServeEngine
 from .faults import Fault, FaultInjector, FaultPlan, poison_lane
+from .policy import FaultPolicy
 from .sharding import align_bucket_sizes, auto_mesh, data_axis_size
 from .tiers import QualityTiers, default_tiers
 
@@ -93,6 +96,7 @@ __all__ = [
     "Fault",
     "FaultInjector",
     "FaultPlan",
+    "FaultPolicy",
     "MicroBatch",
     "PAD_RID",
     "QualityTiers",

@@ -48,7 +48,9 @@ __all__ = [
     "PAD_RID",
     "Request",
     "MicroBatch",
+    "ServeResult",
     "bucket_key",
+    "bucket_label",
     "choose_bucket",
     "cond_struct",
     "form_microbatches",
@@ -108,9 +110,53 @@ class Request:
     enqueued: float | None = None
 
 
+@dataclasses.dataclass(frozen=True)
+class ServeResult:
+    """One served request: final latent plus optional streamed previews."""
+
+    rid: int
+    x0: jnp.ndarray | None
+    #: ``[n_steps, *shape]`` per-step denoised previews (stream=True
+    #: only), in per-request step order — under the step scheduler an
+    #: early-exited lane carries fewer rows than the full solve
+    previews: jnp.ndarray | None = None
+    #: terminal status — x0 is None for everything but "ok":
+    #: - "ok": served (possibly on a degraded retry; see degraded_to)
+    #: - "shed": deadline expired before the request got a lane (step
+    #:   scheduler only)
+    #: - "failed_numerics": the per-lane numerical guard tripped
+    #:   (non-finite state) and retries were exhausted
+    #: - "failed": a host-side fault (model exception, injected failure)
+    #:   outlived the retry budget
+    status: str = "ok"
+    #: solver steps actually run (step scheduler; None under "solve",
+    #: where every request runs its spec's full step count)
+    n_steps: int | None = None
+    #: serve attempts consumed (1 = first try succeeded; retries add 1
+    #: each, so a result that failed after 2 retries reports 3)
+    attempts: int = 1
+    #: degradation-ladder rung the final attempt ran at (a tier name,
+    #: "tau0", or "spec:name/steps"); None when served undegraded
+    degraded_to: str | None = None
+    #: last error string for failed results; None on success
+    error: str | None = None
+
+
 def bucket_key(req: Request) -> tuple:
     """The executor identity this request compiles under."""
     return (req.spec, req.shape, req.dtype, cond_struct(req.cond))
+
+
+def bucket_label(key: tuple) -> str:
+    """Human-readable stats key for one bucket: family/steps/shape/dtype.
+
+    Coarser than the bucket key on purpose (tau, program data, cond
+    values don't change the compiled work per lane-step) — stats
+    aggregate across them.
+    """
+    spec, shape, dtype = key[0], key[1], key[2]
+    return (f"{spec.name}/{spec.n_steps}step/"
+            f"{'x'.join(str(s) for s in shape)}/{dtype}")
 
 
 @dataclasses.dataclass(frozen=True)

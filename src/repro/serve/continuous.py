@@ -45,6 +45,12 @@ solve-granular engine reports the same shape of numbers, so
 ``benchmarks/bench_continuous.py`` compares the two schedulers
 like-for-like.
 
+Retry, quarantine, degradation, the guarded ``on_result`` callback and
+those per-bucket counters live in the engine's
+:class:`~repro.serve.policy.FaultPolicy` (``policy=``), shared with the
+solve scheduler; this module keeps the pending queue, admission,
+deadline and straggler shedding, the tick, harvest and merge.
+
 Each tick is a ``serve.tick`` profiler span (``jax.profiler``'s
 ``TraceAnnotation``, a no-op with no profiler session) with children
 ``serve.admit`` (holding ``serve.new_batch`` and ``serve.join``, whose
@@ -58,7 +64,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Hashable, Sequence
+from typing import Callable, Hashable
 
 import jax
 import jax.numpy as jnp
@@ -66,24 +72,13 @@ import numpy as np
 from jax.profiler import TraceAnnotation as span
 
 from ..core.denoiser import Denoiser
-from ..core.samplers import (SamplerSpec, build_plan, fresh_carry,
-                             make_stepfns, stepwise_cache_stats)
+from ..core.samplers import (build_plan, fresh_carry, make_stepfns,
+                             stepwise_cache_stats)
 from ..runtime import StragglerMonitor
-from .batching import Request, bucket_key
+from .batching import Request, ServeResult, bucket_key, bucket_label
+from .policy import FaultPolicy
 
-__all__ = ["ContinuousBatcher", "RunningBatch", "bucket_label"]
-
-
-def bucket_label(key: tuple) -> str:
-    """Human-readable stats key for one bucket: family/steps/shape/dtype.
-
-    Coarser than the bucket key on purpose (tau, program data, cond
-    values don't change the compiled work per lane-step) — stats
-    aggregate across them.
-    """
-    spec, shape, dtype = key[0], key[1], key[2]
-    return (f"{spec.name}/{spec.n_steps}step/"
-            f"{'x'.join(str(s) for s in shape)}/{dtype}")
+__all__ = ["ContinuousBatcher", "RunningBatch"]
 
 
 class RunningBatch:
@@ -121,43 +116,27 @@ class ContinuousBatcher:
 
     Single-device (the carry is one vmapped batch); the solve-granular
     scheduler remains the mesh path. See the module docstring for the
-    scheduling model.
+    scheduling model; ``policy`` decides retries and quarantine.
     """
 
-    def __init__(self, model_fn: Callable, *, lanes: int = 8,
-                 stream: bool = False,
-                 on_result: Callable | None = None,
+    def __init__(self, model_fn: Callable, *, policy: FaultPolicy,
+                 lanes: int = 8, stream: bool = False,
                  model_key: Hashable | None = None,
                  noise_seed: int = 7, solve_seed: int = 8,
                  max_pending: int | None = None,
-                 result_factory: Callable | None = None,
-                 max_retries: int = 0,
-                 degrade_ladder: Sequence | None = None,
-                 tiers=None,
                  guard_interval: int = 0,
-                 retry_backoff: float = 0.05,
-                 quarantine_after: int = 3,
-                 quarantine_s: float = 1.0,
                  watchdog: StragglerMonitor | None = None,
                  shed_on_straggler: bool = False,
                  fault_injector=None):
         if lanes < 1:
             raise ValueError("need at least one lane")
         self.model_fn = model_fn
+        self.policy = policy
         self.lanes = int(lanes)
         self.stream = stream
-        self.on_result = on_result
         self.model_key = model_key
         self.max_pending = max_pending
-        self._result = result_factory
-        self.max_retries = int(max_retries)
-        self.degrade_ladder = tuple(degrade_ladder) if degrade_ladder \
-            else ()
-        self._tiers = tiers
         self.guard_interval = int(guard_interval)
-        self.retry_backoff = float(retry_backoff)
-        self.quarantine_after = int(quarantine_after)
-        self.quarantine_s = float(quarantine_s)
         self.watchdog = watchdog if watchdog is not None \
             else StragglerMonitor()
         self.shed_on_straggler = shed_on_straggler
@@ -175,19 +154,10 @@ class ContinuousBatcher:
         self._network_factor = 2 if (isinstance(model_fn, Denoiser)
                                      and model_fn.guidance) else 1
         self._stats = {
-            "requests": 0, "completed": 0, "shed": 0, "joins": 0,
-            "migrations": 0, "ticks": 0, "model_evals": 0,
-            "network_evals": 0, "warmups": 0, "serve_s": 0.0,
-            "failed": 0, "failed_numerics": 0, "retries": 0,
-            "degraded": 0, "quarantines": 0, "callback_errors": 0,
-            "straggler_sheds": 0,
+            "requests": 0, "shed": 0, "joins": 0, "migrations": 0,
+            "ticks": 0, "model_evals": 0, "network_evals": 0,
+            "warmups": 0, "serve_s": 0.0, "straggler_sheds": 0,
         }
-        self._buckets: dict[str, dict] = {}
-        #: bucket label -> consecutive failures (reset by any success)
-        self._fail_streak: dict[str, int] = {}
-        #: bucket label -> monotonic time the quarantine lifts
-        self._quarantine: dict[str, float] = {}
-        self._callback_errs: list[str] = []
         self._shed_deadlines = False
 
     # ------------------------------------------------------------- intake
@@ -202,10 +172,15 @@ class ContinuousBatcher:
                 "tick()/run() or shed load upstream")
         if req.enqueued is None:
             req = dataclasses.replace(req, enqueued=time.monotonic())
+        self._requeue(req)
+        self._stats["requests"] += 1
+
+    def _requeue(self, req: Request) -> None:
+        """Queue ``req`` by ``(-priority, deadline, arrival)``; a retry
+        comes back this way and keeps its first enqueue time."""
         dl = float("inf") if req.deadline is None else float(req.deadline)
         self._pending.append(((-int(req.priority), dl, self._seq), req))
         self._seq += 1
-        self._stats["requests"] += 1
 
     def pending(self) -> int:
         return len(self._pending)
@@ -214,106 +189,6 @@ class ContinuousBatcher:
         return sum(b.n_active for b in self._batches)
 
     # ---------------------------------------------------------- internals
-    def _bucket_stats(self, key) -> dict:
-        label = bucket_label(key)
-        if label not in self._buckets:
-            self._buckets[label] = {
-                "ticks": 0, "lane_steps": 0, "active_lane_steps": 0,
-                "wasted_lane_steps": 0,
-            }
-        return self._buckets[label]
-
-    def _make_result(self, **kw):
-        if self._result is not None:
-            return self._result(**kw)
-        return kw
-
-    def _emit(self, res):
-        if self.on_result is not None:
-            try:
-                self.on_result(res)
-            except Exception as e:  # a user callback must not lose
-                self._stats["callback_errors"] += 1  # other results
-                self._callback_errs.append(repr(e))
-                del self._callback_errs[:-8]
-        return res
-
-    # --------------------------------------------------- fault handling
-    @staticmethod
-    def _label_of(req: Request) -> str:
-        return bucket_label(bucket_key(req))
-
-    def _quarantined(self, label: str, now: float) -> bool:
-        until = self._quarantine.get(label)
-        if until is None:
-            return False
-        if now >= until:  # cooldown elapsed: allow a probe
-            del self._quarantine[label]
-            return False
-        return True
-
-    def _note_failure(self, label: str) -> None:
-        """Consecutive-failure counting -> quarantine with cooldown."""
-        n = self._fail_streak.get(label, 0) + 1
-        self._fail_streak[label] = n
-        if n >= self.quarantine_after:
-            self._quarantine[label] = time.monotonic() + self.quarantine_s
-            self._fail_streak[label] = 0
-            self._stats["quarantines"] += 1
-
-    def _note_success(self, label: str) -> None:
-        self._fail_streak.pop(label, None)
-
-    def _degrade(self, req: Request, attempt: int):
-        """Resolve the retry's spec through the degradation ladder.
-
-        Ladder entries are tier names (resolved via the engine's
-        ``QualityTiers``), the literal ``"tau0"`` (the deterministic
-        ODE-limit fallback: same spec with tau=0, program dropped), or
-        explicit ``SamplerSpec`` s. Attempt ``a`` runs at rung
-        ``min(a-1, len(ladder)-1)``; an empty ladder retries unchanged.
-        """
-        if not self.degrade_ladder:
-            return req.spec, req.degraded_to
-        entry = self.degrade_ladder[min(attempt - 1,
-                                        len(self.degrade_ladder) - 1)]
-        if isinstance(entry, SamplerSpec):
-            return entry, f"spec:{entry.name}/{entry.n_steps}"
-        if entry == "tau0":
-            return req.spec.replace(tau=0.0, program=None), "tau0"
-        if self._tiers is None:
-            raise ValueError(
-                f"degrade ladder names tier {entry!r} but the engine "
-                "has no QualityTiers to resolve it")
-        return self._tiers.resolve(entry), entry
-
-    def _fail(self, req: Request, err, *, numerics: bool) -> list:
-        """Retry (bounded, degraded, backed off) or emit a failure."""
-        if req.attempt < self.max_retries:
-            self._stats["retries"] += 1
-            attempt = req.attempt + 1
-            spec, rung = self._degrade(req, attempt)
-            # numerics failures retry immediately (a fresh fold_in
-            # subkey / degraded spec is the fix); host-side faults back
-            # off exponentially to ride out transient breakage
-            not_before = 0.0 if numerics else \
-                time.monotonic() + self.retry_backoff * (2 ** req.attempt)
-            retry = dataclasses.replace(
-                req, spec=spec, attempt=attempt, not_before=not_before,
-                degraded_to=rung)
-            dl = float("inf") if retry.deadline is None \
-                else float(retry.deadline)
-            self._pending.append(
-                ((-int(retry.priority), dl, self._seq), retry))
-            self._seq += 1
-            return []
-        status = "failed_numerics" if numerics else "failed"
-        self._stats[status] += 1
-        return [self._emit(self._make_result(
-            rid=req.rid, x0=None, status=status,
-            attempts=req.attempt + 1, degraded_to=req.degraded_to,
-            error=f"{type(err).__name__}: {err}"))]
-
     def _new_batch(self, req: Request) -> RunningBatch:
         with span("serve.new_batch"):
             key = bucket_key(req)
@@ -402,14 +277,14 @@ class ContinuousBatcher:
         shed_deadlines = self._shed_deadlines
         self._shed_deadlines = False
         results, held = [], []
-        # snapshot: _fail() re-enqueues retries onto self._pending, and
+        # snapshot: policy.fail() requeues retries onto self._pending, and
         # those must wait for the NEXT admission pass (backoff aside,
         # re-admitting a failing request in the same pass would loop)
         queue, self._pending = self._pending, []
         for sort_key, req in queue:
             if req.deadline is not None and now > float(req.deadline):
                 self._stats["shed"] += 1
-                results.append(self._emit(self._make_result(
+                results.append(self.policy.emit(ServeResult(
                     rid=req.rid, x0=None, status="shed")))
                 continue
             if shed_deadlines and req.deadline is not None:
@@ -418,11 +293,10 @@ class ContinuousBatcher:
                 # of letting it expire in the queue
                 self._stats["shed"] += 1
                 self._stats["straggler_sheds"] += 1
-                results.append(self._emit(self._make_result(
+                results.append(self.policy.emit(ServeResult(
                     rid=req.rid, x0=None, status="shed")))
                 continue
-            label = self._label_of(req)
-            if req.not_before > now or self._quarantined(label, now):
+            if self.policy.held(req, now):
                 held.append((sort_key, req))
                 continue
             key = bucket_key(req)
@@ -439,8 +313,9 @@ class ContinuousBatcher:
                     lane_home = (b, 0)
                 self._join(lane_home[0], lane_home[1], req)
             except Exception as err:
-                self._note_failure(label)
-                results.extend(self._fail(req, err, numerics=False))
+                results.extend(self.policy.fail(
+                    bucket_label(key), (req,), err, numerics=False,
+                    requeue=self._requeue))
         self._pending.extend(held)
         return results
 
@@ -471,11 +346,10 @@ class ContinuousBatcher:
                 if bad[lane]:
                     # in-graph guard tripped: the lane was already masked
                     # out; free it and retry/fail the request
-                    self._note_failure(label)
-                    results.extend(self._fail(
-                        req, ArithmeticError(
+                    results.extend(self.policy.fail(
+                        label, (req,), ArithmeticError(
                             f"non-finite state at step {int(steps[lane])}"),
-                        numerics=True))
+                        numerics=True, requeue=self._requeue))
                     batch.requests[lane] = None
                     batch.previews[lane] = []
                     continue
@@ -484,17 +358,11 @@ class ContinuousBatcher:
                 previews = None
                 if self.stream:
                     previews = jnp.stack(batch.previews[lane])
-                if req.degraded_to is not None:
-                    self._stats["degraded"] += 1
-                results.append(self._emit(self._make_result(
-                    rid=req.rid, x0=batch.carry["x_final"][lane],
-                    previews=previews, status="ok",
-                    n_steps=int(steps[lane]), attempts=req.attempt + 1,
-                    degraded_to=req.degraded_to)))
+                results.append(self.policy.done(
+                    label, req, batch.carry["x_final"][lane], previews,
+                    n_steps=int(steps[lane])))
                 batch.requests[lane] = None
                 batch.previews[lane] = []
-                self._stats["completed"] += 1
-                self._note_success(label)
             return results
 
     def _merge(self) -> None:
@@ -536,12 +404,10 @@ class ContinuousBatcher:
         requests (retry path included) and drop the batch — its carry
         may hold a poisoned dispatch. The compiled step functions stay
         cached, so a post-quarantine probe re-warms nothing."""
-        label = bucket_label(batch.key)
-        self._note_failure(label)
-        results = []
-        for req in batch.requests:
-            if req is not None:
-                results.extend(self._fail(req, err, numerics=False))
+        results = self.policy.fail(
+            bucket_label(batch.key),
+            [r for r in batch.requests if r is not None], err,
+            numerics=False, requeue=self._requeue)
         self._batches.remove(batch)
         self._rr = 0
         return results
@@ -578,11 +444,8 @@ class ContinuousBatcher:
                 evals = batch.fns.adapter.evals_per_tick * n_active
                 self._stats["model_evals"] += evals
                 self._stats["network_evals"] += evals * self._network_factor
-                bs = self._bucket_stats(batch.key)
-                bs["ticks"] += 1
-                bs["lane_steps"] += batch.lanes
-                bs["active_lane_steps"] += n_active
-                bs["wasted_lane_steps"] += batch.lanes - n_active
+                self.policy.count_lane_steps(bucket_label(batch.key), 1,
+                                             batch.lanes, n_active)
                 results.extend(self._harvest(batch, aux))
             except Exception as err:
                 results.extend(self._contain(batch, err))
@@ -597,18 +460,6 @@ class ContinuousBatcher:
                 self._shed_deadlines = True
             return results
 
-    def _next_wake(self) -> float:
-        """Earliest monotonic time any held pending request becomes
-        admittable (backoff expiry or quarantine lift); inf if none."""
-        wake = float("inf")
-        for _, req in self._pending:
-            w = req.not_before
-            until = self._quarantine.get(self._label_of(req))
-            if until is not None:
-                w = max(w, until)
-            wake = min(wake, w)
-        return wake
-
     def run(self) -> list:
         """Drain pending + running work; results in completion order."""
         out = []
@@ -622,54 +473,24 @@ class ContinuousBatcher:
             # pending-only: everything is backed off or quarantined —
             # sleep until the earliest becomes admittable instead of
             # spinning (quarantine cooldowns are wall-clock)
-            wake = self._next_wake()
-            if wake == float("inf"):
+            if not self.policy.wait(r for _, r in self._pending):
                 break
-            wait = wake - time.monotonic()
-            if wait > 0:
-                time.sleep(min(wait, 0.05))
         return out
 
     # -------------------------------------------------------------- stats
     def stats(self) -> dict:
-        s = dict(self._stats)
+        s = {**self._stats, **self.policy.stats()}
         dt = s["serve_s"]
         s["requests_per_s"] = s["completed"] / dt if dt > 0 else 0.0
         s["model_evals_per_s"] = s["model_evals"] / dt if dt > 0 else 0.0
-        buckets = {}
-        for label, b in self._buckets.items():
-            b = dict(b)
-            b["occupancy"] = (b["active_lane_steps"] / b["lane_steps"]
-                              if b["lane_steps"] else 0.0)
-            buckets[label] = b
-        s["buckets"] = buckets
         s["stepwise_cache"] = stepwise_cache_stats()
-        s["callback_error_messages"] = list(self._callback_errs)
         s["straggler_events"] = len(self.watchdog.events)
         return s
 
     def health(self) -> dict:
         """Machine-readable health snapshot (no device sync)."""
-        now = time.monotonic()
-        quarantined = {label: round(until - now, 6)
-                       for label, until in self._quarantine.items()
-                       if until > now}
-        s = self._stats
-        return {
-            "status": "degraded" if quarantined else "ok",
-            "scheduler": "step",
-            "pending": len(self._pending),
-            "active": self.active(),
-            "running_batches": len(self._batches),
-            "quarantined": quarantined,
-            "consecutive_failures": dict(self._fail_streak),
-            "completed": s["completed"],
-            "failed": s["failed"],
-            "failed_numerics": s["failed_numerics"],
-            "retries": s["retries"],
-            "degraded_results": s["degraded"],
-            "shed": s["shed"],
-            "quarantines": s["quarantines"],
-            "callback_errors": s["callback_errors"],
-            "straggler_events": len(self.watchdog.events),
-        }
+        return {**self.policy.health(), "scheduler": "step",
+                "pending": len(self._pending), "active": self.active(),
+                "running_batches": len(self._batches),
+                "shed": self._stats["shed"],
+                "straggler_events": len(self.watchdog.events)}

@@ -41,6 +41,12 @@ guidance every guided evaluation is one fused network forward over a
 wastes two network lanes instead of one). Padded lanes are reported
 separately as ``padded_slots`` (they cost compute but serve nobody).
 
+Retry, quarantine, degradation, the guarded ``on_result`` callback and
+the per-bucket lane-step counters live in one
+:class:`~repro.serve.policy.FaultPolicy`, which the engine builds and
+shares with the step scheduler; this module keeps the solve scheduler's
+FIFO queue, microbatch dispatch and post-solve guard.
+
 Each microbatch is a ``serve.microbatch`` profiler span (as in
 :mod:`repro.serve.continuous`) with children ``serve.warm`` (only when
 it compiles), ``serve.prepare`` (keys and the initial noise),
@@ -49,9 +55,8 @@ it compiles), ``serve.prepare`` (keys and the initial noise),
 
 from __future__ import annotations
 
-import dataclasses
 import time
-from typing import Any, Callable, Hashable, Sequence
+from typing import Callable, Hashable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -62,45 +67,14 @@ from ..core.denoiser import Denoiser
 from ..core.samplers import (SamplerSpec, build_plan, compile_cache_stats,
                              sample_batched, sample_sharded, warmup)
 from ..runtime import StragglerMonitor
-from .batching import (MicroBatch, Request, bucket_key, fold_keys,
-                       form_microbatches, retry_fold)
-from .continuous import ContinuousBatcher, bucket_label
+from .batching import (MicroBatch, Request, ServeResult, bucket_label,
+                       fold_keys, form_microbatches, retry_fold)
+from .continuous import ContinuousBatcher
+from .policy import FaultPolicy
 from .sharding import align_bucket_sizes, data_axis_size
 from .tiers import QualityTiers, default_tiers
 
-__all__ = ["ServeEngine", "ServeResult"]
-
-
-@dataclasses.dataclass(frozen=True)
-class ServeResult:
-    """One served request: final latent plus optional streamed previews."""
-
-    rid: int
-    x0: jnp.ndarray | None
-    #: ``[n_steps, *shape]`` per-step denoised previews (stream=True
-    #: only), in per-request step order — under the step scheduler an
-    #: early-exited lane carries fewer rows than the full solve
-    previews: jnp.ndarray | None = None
-    #: terminal status — x0 is None for everything but "ok":
-    #: - "ok": served (possibly on a degraded retry; see degraded_to)
-    #: - "shed": deadline expired before the request got a lane (step
-    #:   scheduler only)
-    #: - "failed_numerics": the per-lane numerical guard tripped
-    #:   (non-finite state) and retries were exhausted
-    #: - "failed": a host-side fault (model exception, injected failure)
-    #:   outlived the retry budget
-    status: str = "ok"
-    #: solver steps actually run (step scheduler; None under "solve",
-    #: where every request runs its spec's full step count)
-    n_steps: int | None = None
-    #: serve attempts consumed (1 = first try succeeded; retries add 1
-    #: each, so a result that failed after 2 retries reports 3)
-    attempts: int = 1
-    #: degradation-ladder rung the final attempt ran at (a tier name,
-    #: "tau0", or "spec:name/steps"); None when served undegraded
-    degraded_to: str | None = None
-    #: last error string for failed results; None on success
-    error: str | None = None
+__all__ = ["ServeEngine"]
 
 
 class ServeEngine:
@@ -214,19 +188,16 @@ class ServeEngine:
                 bucket_sizes, data_axis_size(mesh, data_axis))
         self.bucket_sizes = tuple(sorted(set(int(b) for b in bucket_sizes)))
         self.stream = stream
-        self.on_result = on_result
         self.model_key = model_key
         self.donate = donate
         self.tiers = tiers if tiers is not None else default_tiers()
         self.scheduler = scheduler
         self.max_pending = max_pending
-        self.max_retries = int(max_retries)
-        self.degrade_ladder = tuple(degrade_ladder) if degrade_ladder \
-            else ()
         self.guard_interval = int(guard_interval)
-        self.retry_backoff = float(retry_backoff)
-        self.quarantine_after = int(quarantine_after)
-        self.quarantine_s = float(quarantine_s)
+        self.policy = FaultPolicy(
+            tiers=self.tiers, on_result=on_result, max_retries=max_retries,
+            degrade_ladder=degrade_ladder, retry_backoff=retry_backoff,
+            quarantine_after=quarantine_after, quarantine_s=quarantine_s)
         self.watchdog = watchdog if watchdog is not None \
             else StragglerMonitor()
         self.shed_on_straggler = shed_on_straggler
@@ -239,32 +210,17 @@ class ServeEngine:
         self._stats = {
             "requests": 0, "microbatches": 0, "padded_slots": 0,
             "model_evals": 0, "network_evals": 0, "warmups": 0,
-            "serve_s": 0.0, "completed": 0,
-            "failed": 0, "failed_numerics": 0, "retries": 0,
-            "degraded": 0, "quarantines": 0, "callback_errors": 0,
+            "serve_s": 0.0,
         }
-        self._buckets: dict[str, dict] = {}
-        self._fail_streak: dict[str, int] = {}
-        self._quarantine: dict[str, float] = {}
-        self._callback_errs: list[str] = []
         self._batcher = None
         if scheduler == "step":
             self._batcher = ContinuousBatcher(
-                model_fn, lanes=lanes, stream=stream,
-                on_result=on_result, model_key=model_key,
+                model_fn, lanes=lanes, stream=stream, model_key=model_key,
                 noise_seed=noise_seed, solve_seed=solve_seed,
-                max_pending=max_pending,
-                result_factory=ServeResult,
-                max_retries=self.max_retries,
-                degrade_ladder=self.degrade_ladder,
-                tiers=self.tiers,
-                guard_interval=self.guard_interval,
-                retry_backoff=self.retry_backoff,
-                quarantine_after=self.quarantine_after,
-                quarantine_s=self.quarantine_s,
+                max_pending=max_pending, guard_interval=self.guard_interval,
                 watchdog=self.watchdog,
                 shed_on_straggler=shed_on_straggler,
-                fault_injector=fault_injector)
+                fault_injector=fault_injector, policy=self.policy)
 
     # ------------------------------------------------------------- intake
     def submit(self, spec: SamplerSpec | None, shape: Sequence[int],
@@ -339,91 +295,14 @@ class ServeEngine:
             return self._batcher.pending()
         return len(self._queue)
 
-    # --------------------------------------------------- fault handling
-    # (solve scheduler; the step scheduler's ContinuousBatcher carries
-    # its own copy of this state so containment is per-scheduler-tick)
-    def _emit(self, res: ServeResult) -> ServeResult:
-        if self.on_result is not None:
-            try:
-                self.on_result(res)
-            except Exception as e:  # a user callback must not lose
-                self._stats["callback_errors"] += 1  # other results
-                self._callback_errs.append(repr(e))
-                del self._callback_errs[:-8]
-        return res
-
-    def _quarantined(self, label: str, now: float) -> bool:
-        until = self._quarantine.get(label)
-        if until is None:
-            return False
-        if now >= until:  # cooldown elapsed: allow a probe
-            del self._quarantine[label]
-            return False
-        return True
-
-    def _note_failure(self, label: str) -> None:
-        n = self._fail_streak.get(label, 0) + 1
-        self._fail_streak[label] = n
-        if n >= self.quarantine_after:
-            self._quarantine[label] = time.monotonic() + self.quarantine_s
-            self._fail_streak[label] = 0
-            self._stats["quarantines"] += 1
-
-    def _note_success(self, label: str) -> None:
-        self._fail_streak.pop(label, None)
-
-    def _degrade(self, req: Request, attempt: int):
-        if not self.degrade_ladder:
-            return req.spec, req.degraded_to
-        entry = self.degrade_ladder[min(attempt - 1,
-                                        len(self.degrade_ladder) - 1)]
-        if isinstance(entry, SamplerSpec):
-            return entry, f"spec:{entry.name}/{entry.n_steps}"
-        if entry == "tau0":
-            return req.spec.replace(tau=0.0, program=None), "tau0"
-        return self.tiers.resolve(entry), entry
-
-    def _fail(self, req: Request, err, *, numerics: bool) -> list:
-        """Retry (bounded, degraded, backed off) or emit a failure."""
-        if req.attempt < self.max_retries:
-            self._stats["retries"] += 1
-            attempt = req.attempt + 1
-            spec, rung = self._degrade(req, attempt)
-            not_before = 0.0 if numerics else \
-                time.monotonic() + self.retry_backoff * (2 ** req.attempt)
-            self._queue.append(dataclasses.replace(
-                req, spec=spec, attempt=attempt, not_before=not_before,
-                degraded_to=rung))
-            return []
-        status = "failed_numerics" if numerics else "failed"
-        self._stats[status] += 1
-        return [self._emit(ServeResult(
-            rid=req.rid, x0=None, status=status,
-            attempts=req.attempt + 1, degraded_to=req.degraded_to,
-            error=f"{type(err).__name__}: {err}"))]
-
     def _eligible(self) -> tuple[list[Request], list[Request]]:
         """Split the queue into (servable now, held) — held requests are
         backed off or their bucket is quarantined."""
         now = time.monotonic()
         ok, held = [], []
         for r in self._queue:
-            label = bucket_label(bucket_key(r))
-            if r.not_before > now or self._quarantined(label, now):
-                held.append(r)
-            else:
-                ok.append(r)
+            (held if self.policy.held(r, now) else ok).append(r)
         return ok, held
-
-    def _next_wake(self) -> float:
-        wake = float("inf")
-        for r in self._queue:
-            w = r.not_before
-            until = self._quarantine.get(bucket_label(bucket_key(r)))
-            if until is not None:
-                w = max(w, until)
-            wake = min(wake, w)
-        return wake
 
     def _serve_safe(self, mb: MicroBatch) -> list[ServeResult]:
         """Containment boundary: a fault anywhere in one microbatch's
@@ -434,11 +313,9 @@ class ServeEngine:
             with span("serve.microbatch"):
                 return self._serve(mb)
         except Exception as err:
-            self._note_failure(bucket_label(mb.key))
-            results = []
-            for req in mb.requests:
-                results.extend(self._fail(req, err, numerics=False))
-            return results
+            return self.policy.fail(bucket_label(mb.key), mb.requests, err,
+                                    numerics=False,
+                                    requeue=self._queue.append)
 
     # ------------------------------------------------------------ serving
     def warmup_bucket(self, mb: MicroBatch) -> None:
@@ -498,12 +375,8 @@ class ServeEngine:
             if not eligible:
                 # everything is backed off or quarantined — sleep until
                 # the earliest becomes admittable instead of spinning
-                wake = self._next_wake()
-                if wake == float("inf"):
+                if not self.policy.wait(self._queue):
                     break
-                wait = wake - time.monotonic()
-                if wait > 0:
-                    time.sleep(min(wait, 0.05))
                 continue
             self._queue = held  # retries from _serve_safe append here
             for mb in form_microbatches(eligible, self.bucket_sizes):
@@ -569,13 +442,8 @@ class ServeEngine:
             # step scheduler: here every lane rides the full solve, so a
             # padded lane wastes n_steps lane-steps in one indivisible chunk
             label = bucket_label(mb.key)
-            bs = self._buckets.setdefault(label, {
-                "ticks": 0, "lane_steps": 0, "active_lane_steps": 0,
-                "wasted_lane_steps": 0})
-            bs["ticks"] += spec.n_steps
-            bs["lane_steps"] += mb.size * spec.n_steps
-            bs["active_lane_steps"] += n_real * spec.n_steps
-            bs["wasted_lane_steps"] += mb.n_padded * spec.n_steps
+            self.policy.count_lane_steps(label, spec.n_steps, mb.size,
+                                         n_real)
 
             # post-solve numerical guard (the solve scheduler has no
             # in-graph per-step check — the whole solve is one dispatch —
@@ -588,19 +456,14 @@ class ServeEngine:
             results = []
             for lane, req in enumerate(mb.requests):  # pad lanes dropped here
                 if bad[lane]:
-                    self._note_failure(label)
-                    results.extend(self._fail(
-                        req, ArithmeticError("non-finite final latent"),
-                        numerics=True))
+                    results.extend(self.policy.fail(
+                        label, (req,),
+                        ArithmeticError("non-finite final latent"),
+                        numerics=True, requeue=self._queue.append))
                     continue
-                if req.degraded_to is not None:
-                    self._stats["degraded"] += 1
-                results.append(self._emit(ServeResult(
-                    rid=req.rid, x0=x0[lane],
-                    previews=previews[lane] if previews is not None else None,
-                    attempts=req.attempt + 1, degraded_to=req.degraded_to)))
-                self._stats["completed"] += 1
-                self._note_success(label)
+                results.append(self.policy.done(
+                    label, req, x0[lane],
+                    previews[lane] if previews is not None else None))
             return results
 
     # -------------------------------------------------------------- stats
@@ -625,20 +488,12 @@ class ServeEngine:
             s = self._batcher.stats()
             s["compile_cache"] = compile_cache_stats()
             return s
-        s = dict(self._stats)
-        s["callback_error_messages"] = list(self._callback_errs)
+        s = {**self._stats, **self.policy.stats()}
         s["straggler_events"] = len(self.watchdog.events)
         dt = s["serve_s"]
         s["requests_per_s"] = s["requests"] / dt if dt > 0 else 0.0
         s["model_evals_per_s"] = s["model_evals"] / dt if dt > 0 else 0.0
         s["network_evals_per_s"] = s["network_evals"] / dt if dt > 0 else 0.0
-        buckets = {}
-        for label, b in self._buckets.items():
-            b = dict(b)
-            b["occupancy"] = (b["active_lane_steps"] / b["lane_steps"]
-                              if b["lane_steps"] else 0.0)
-            buckets[label] = b
-        s["buckets"] = buckets
         s["compile_cache"] = compile_cache_stats()
         return s
 
@@ -649,26 +504,8 @@ class ServeEngine:
         labels to seconds of cooldown remaining."""
         if self._batcher is not None:
             return self._batcher.health()
-        now = time.monotonic()
-        quarantined = {lbl: round(until - now, 6)
-                       for lbl, until in self._quarantine.items()
-                       if until > now}
-        s = self._stats
-        return {
-            "status": "degraded" if quarantined else "ok",
-            "scheduler": "solve",
-            "pending": len(self._queue),
-            "active": 0,  # solve dispatches are synchronous
-            "running_batches": 0,
-            "quarantined": quarantined,
-            "consecutive_failures": dict(self._fail_streak),
-            "completed": s["completed"],
-            "failed": s["failed"],
-            "failed_numerics": s["failed_numerics"],
-            "retries": s["retries"],
-            "degraded_results": s["degraded"],
-            "shed": 0,
-            "quarantines": s["quarantines"],
-            "callback_errors": s["callback_errors"],
-            "straggler_events": len(self.watchdog.events),
-        }
+        return {**self.policy.health(), "scheduler": "solve",
+                "pending": len(self._queue),
+                "active": 0,  # solve dispatches are synchronous
+                "running_batches": 0, "shed": 0,
+                "straggler_events": len(self.watchdog.events)}

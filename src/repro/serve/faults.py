@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..runtime import InjectedFailure
+from .batching import bucket_label
 
 __all__ = ["Fault", "FaultPlan", "FaultInjector", "poison_lane"]
 
@@ -48,7 +49,7 @@ class Fault:
     or microbatch index (solve scheduler) at which the fault *arms*; a
     ``"nan"`` fault targeting a ``rid`` stays armed until that request
     occupies a lane of the dispatched batch. ``bucket`` (a substring of
-    the bucket label, see :func:`~repro.serve.continuous.bucket_label`)
+    the bucket label, see :func:`~repro.serve.batching.bucket_label`)
     scopes ``"raise"``/``"latency"`` faults to one bucket's dispatches;
     None fires on any batch."""
 
@@ -147,7 +148,6 @@ class FaultInjector:
         """Called by the continuous batcher right before advancing one
         running batch; mutates ``batch.carry`` (nan), sleeps (latency),
         or raises :class:`InjectedFailure` (raise)."""
-        from .continuous import bucket_label
         label = bucket_label(batch.key)
         for idx, f in list(self._armed(tick, label)):
             if f.kind == "latency":
@@ -173,7 +173,6 @@ class FaultInjector:
         """Called by the solve scheduler with the microbatch's initial
         noise; returns ``x_T`` (possibly with a target lane NaN'd), or
         sleeps/raises like ``on_tick``."""
-        from .continuous import bucket_label
         label = bucket_label(mb.key)
         for idx, f in list(self._armed(index, label)):
             if f.kind == "latency":

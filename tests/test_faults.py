@@ -190,15 +190,23 @@ def test_retry_succeeds_after_transient_raise(scheduler):
         assert any(out[r].attempts == 2 for r in range(3))
 
 
-def test_degradation_ladder_tau0_after_repeated_numerics():
+# the solve injector counts completed microbatches, the step one ticks:
+# each plan below reaches the request on its first two attempts
+LADDER_NANS = {"solve": (0, 1), "step": (2, 6)}
+
+
+@pytest.mark.parametrize("scheduler", ["solve", "step"])
+def test_degradation_ladder_tau0_after_repeated_numerics(scheduler):
     """Two NaN faults chase the same rid across retries: attempt 1
     degrades to tau=0 (rung 0 of the ladder) and attempt 3 completes
-    there — all under ONE compiled step family (tau is data)."""
+    there — all under ONE compiled executor (tau is data)."""
     clear_stepwise_cache()
-    inj = FaultInjector(FaultPlan((Fault("nan", tick=2, rid=0),
-                                   Fault("nan", tick=6, rid=0))))
-    eng = step_engine(guard_interval=1, max_retries=2,
-                      degrade_ladder=("tau0",), fault_injector=inj)
+    clear_compile_cache()
+    inj = FaultInjector(FaultPlan(tuple(
+        Fault("nan", tick=t, rid=0) for t in LADDER_NANS[scheduler])))
+    eng = step_engine(scheduler=scheduler, guard_interval=1,
+                      max_retries=2, degrade_ladder=("tau0",),
+                      fault_injector=inj)
     eng.submit(SPEC, SHAPE, rid=0)
     (res,) = eng.run()
     assert res.status == "ok"
@@ -209,7 +217,10 @@ def test_degradation_ladder_tau0_after_repeated_numerics():
     s = eng.stats()
     assert s["retries"] == 2 and s["failed_numerics"] == 0
     assert s["degraded"] == 1
-    assert s["stepwise_cache"]["misses"] == 1, s["stepwise_cache"]
+    if scheduler == "step":
+        assert s["stepwise_cache"]["misses"] == 1, s["stepwise_cache"]
+    else:
+        assert s["compile_cache"]["misses"] == 1, s["compile_cache"]
 
 
 def test_degraded_tau0_matches_explicit_tau0_submission():
@@ -233,24 +244,50 @@ def test_degraded_tau0_matches_explicit_tau0_submission():
 
 
 # --------------------------------------------------- quarantine/watchdog
-def test_quarantine_after_consecutive_failures_then_recovery():
+# a failed microbatch does not count, so both solve raises are at 0
+QUARANTINE_RAISES = {"solve": (0, 0), "step": (0, 1)}
+
+
+@pytest.mark.parametrize("scheduler", ["solve", "step"])
+def test_quarantine_after_consecutive_failures_then_recovery(scheduler):
     """Two consecutive injected failures quarantine the bucket; the
     pending retry is HELD (not dropped) through the cooldown and the
     post-cooldown probe completes it."""
-    inj = FaultInjector(FaultPlan((Fault("raise", tick=0),
-                                   Fault("raise", tick=1))))
-    eng = step_engine(max_retries=3, retry_backoff=0.01,
-                      quarantine_after=2, quarantine_s=0.1,
-                      fault_injector=inj)
+    inj = FaultInjector(FaultPlan(tuple(
+        Fault("raise", tick=t) for t in QUARANTINE_RAISES[scheduler])))
+    eng = step_engine(scheduler=scheduler, max_retries=3,
+                      retry_backoff=0.01, quarantine_after=2,
+                      quarantine_s=0.1, fault_injector=inj)
     eng.submit(SPEC, SHAPE, rid=0)
     t0 = time.monotonic()
     (res,) = eng.run()
     assert res.status == "ok" and res.attempts == 3
+    assert len([f for f in inj.fired if f[0] == "raise"]) == 2
     s = eng.stats()
     assert s["quarantines"] == 1
     assert time.monotonic() - t0 >= 0.1  # sat out the cooldown
     h = eng.health()
     assert h["status"] == "ok" and h["quarantined"] == {}
+
+
+HEALTH_KEYS = {
+    "status", "scheduler", "pending", "active", "running_batches",
+    "quarantined", "consecutive_failures", "completed", "failed",
+    "failed_numerics", "retries", "degraded_results", "shed",
+    "quarantines", "callback_errors", "straggler_events"}
+SHARED_STATS = {
+    "requests", "completed", "model_evals", "network_evals", "warmups",
+    "serve_s", "failed", "failed_numerics", "retries", "degraded",
+    "quarantines", "callback_errors", "callback_error_messages",
+    "straggler_events", "requests_per_s", "model_evals_per_s", "buckets",
+    "compile_cache"}
+STATS_KEYS = {
+    "solve": SHARED_STATS | {"microbatches", "padded_slots",
+                             "network_evals_per_s"},
+    "step": SHARED_STATS | {"shed", "joins", "migrations", "ticks",
+                            "straggler_sheds", "stepwise_cache"}}
+BUCKET_KEYS = {"ticks", "lane_steps", "active_lane_steps",
+               "wasted_lane_steps", "occupancy"}
 
 
 def test_health_snapshot_both_schedulers():
@@ -262,6 +299,17 @@ def test_health_snapshot_both_schedulers():
                   "completed", "failed", "failed_numerics", "retries",
                   "quarantines", "callback_errors", "straggler_events"):
             assert k in h, k
+        # each scheduler reports exactly its own keys, before and after
+        # it has served a bucket
+        assert set(h) == HEALTH_KEYS
+        assert set(eng.stats()) == STATS_KEYS[scheduler]
+        eng.submit(SPEC, SHAPE, rid=0)
+        eng.run()
+        assert set(eng.health()) == HEALTH_KEYS
+        s = eng.stats()
+        assert set(s) == STATS_KEYS[scheduler]
+        (bucket,) = s["buckets"].values()
+        assert set(bucket) == BUCKET_KEYS
     # a quarantined bucket flips status to degraded with time remaining
     eng = ServeEngine(_model_raising_on(9), quarantine_after=1,
                       quarantine_s=30.0)

@@ -22,8 +22,9 @@ import pytest
 
 from repro.core import Denoiser, get_schedule
 from repro.core.samplers import SamplerSpec, build_plan, warmup
-from repro.serve import (Fault, FaultInjector, FaultPlan, Request,
-                         ServeEngine, continuous, engine)
+from repro.serve import (Fault, FaultInjector, FaultPlan, FaultPolicy,
+                         Request, ServeEngine, continuous, default_tiers,
+                         engine)
 
 SCHED = get_schedule("vp_linear")
 SPEC = SamplerSpec(name="sa", schedule=SCHED, n_steps=6, tau=0.7)
@@ -135,12 +136,15 @@ def test_a_retry_keeps_its_first_enqueue_time(spans):
 
 
 def test_enqueue_stamps_once():
-    b = continuous.ContinuousBatcher(STABLE, lanes=2, max_retries=1)
+    b = continuous.ContinuousBatcher(
+        STABLE, lanes=2,
+        policy=FaultPolicy(tiers=default_tiers(), max_retries=1))
     b.enqueue(Request(rid=0, spec=SPEC, shape=SHAPE))
     b.enqueue(Request(rid=1, spec=SPEC, shape=SHAPE, enqueued=12.5))
     first, given = [req for _, req in b._pending]
     assert first.enqueued is not None and given.enqueued == 12.5
-    assert b._fail(first, ArithmeticError("nan"), numerics=True) == []
+    assert b.policy.fail("sa", (first,), ArithmeticError("nan"),
+                         numerics=True, requeue=b._requeue) == []
     retry = b._pending[-1][1]
     assert retry.attempt == 1 and retry.enqueued == first.enqueued
 
